@@ -43,12 +43,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
-import tempfile
 import time
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 LANES = (8, 32, 128)
 N_EMU_DEVICES = 8
@@ -99,8 +94,11 @@ def _measure(lanes, n_emu, *, n_devices, m_edges, h_cohort, alloc_steps,
     from repro.data import make_dataset, partition_noniid
     from repro.core.cost_model import SystemParams, sample_population
 
-    assert len(jax.devices()) == n_emu, (
-        f"child expected {n_emu} devices, got {len(jax.devices())}")
+    emulated = jax.default_backend() == "cpu"
+    if emulated:
+        assert len(jax.devices()) == n_emu, (
+            f"child expected {n_emu} devices, got {len(jax.devices())}")
+    dev = jax.devices()[0]
     counts = _count_engine_calls()
     sp = SystemParams(n_devices=n_devices, n_edges=m_edges, L=1, Q=1,
                       d_range=(1, 2))
@@ -112,9 +110,12 @@ def _measure(lanes, n_emu, *, n_devices, m_edges, h_cohort, alloc_steps,
 
     out = {"config": {"M": m_edges, "N": n_devices, "H": h_cohort,
                       "alloc_steps": alloc_steps, "rounds": rounds,
-                      "emulated_devices": n_emu,
+                      "emulated_devices": n_emu if emulated else 0,
                       "host_cores": os.cpu_count(),
-                      "mode": "cpu-emulation"},
+                      "mode": "cpu-emulation" if emulated else dev.platform,
+                      "device": {"platform": dev.platform,
+                                 "kind": dev.device_kind,
+                                 "count": len(jax.devices())}},
            "lanes": {}}
     variants = (("perround_host", False, False),
                 ("perround_shard", True, False),
@@ -175,38 +176,16 @@ def _child_main(args):
 
 # -------------------------------------------------------------- parent
 
-def _spawn(cfg: dict, n_emu: int) -> dict:
-    from repro.utils import forced_device_env
-
-    env = forced_device_env(
-        n_emu, pythonpath=(os.path.join(REPO_ROOT, "src"), REPO_ROOT))
-    with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as tf:
-        out_path = tf.name
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_sweep_fused",
-             "--child", "--out", out_path,
-             "--config", json.dumps({**cfg, "n_emu": n_emu})],
-            env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-            timeout=3600)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"sweep-fused child failed:\n{proc.stdout}\n{proc.stderr}")
-        with open(out_path) as fh:
-            return json.load(fh)
-    finally:
-        os.unlink(out_path)
-
-
 def run(out_json: str = "BENCH_sweep_fused.json", lanes=LANES,
         n_emu: int = N_EMU_DEVICES, rounds: int = ROUNDS,
         check_claims: bool = True):
-    from benchmarks.common import emit
+    from benchmarks.common import emit, measure_on_devices
 
-    result = _spawn(dict(lanes=list(lanes), n_devices=N_DEVICES,
-                         m_edges=M_EDGES, h_cohort=H_COHORT,
-                         alloc_steps=ALLOC_STEPS, rounds=rounds,
-                         repeats=REPEATS, n_train=120, n_test=20), n_emu)
+    result = measure_on_devices(
+        "benchmarks.bench_sweep_fused", _measure,
+        dict(lanes=list(lanes), n_devices=N_DEVICES, m_edges=M_EDGES,
+             h_cohort=H_COHORT, alloc_steps=ALLOC_STEPS, rounds=rounds,
+             repeats=REPEATS, n_train=120, n_test=20), n_emu)
     os.makedirs(os.path.dirname(out_json) or ".", exist_ok=True)
     with open(out_json, "w") as fh:
         json.dump(result, fh, indent=1)
@@ -256,17 +235,20 @@ def run_smoke(out_json: str = "results/BENCH_sweep_fused_smoke.json"):
     """Tiny-shape CI guard: 2 emulated devices, asserts all four engine
     variants run end-to-end, the fused paths really are one dispatch
     (the child asserts the counter) and the JSON is well-formed."""
-    from benchmarks.common import emit
+    from benchmarks.common import emit, measure_on_devices
 
-    result = _spawn(dict(lanes=[2, 4], n_devices=8, m_edges=2, h_cohort=4,
-                         alloc_steps=25, rounds=2, repeats=1, n_train=60,
-                         n_test=20), 2)
+    result = measure_on_devices(
+        "benchmarks.bench_sweep_fused", _measure,
+        dict(lanes=[2, 4], n_devices=8, m_edges=2, h_cohort=4,
+             alloc_steps=25, rounds=2, repeats=1, n_train=60, n_test=20),
+        2)
     os.makedirs(os.path.dirname(out_json) or ".", exist_ok=True)
     with open(out_json, "w") as fh:
         json.dump(result, fh, indent=1)
     with open(out_json) as fh:
         loaded = json.load(fh)
-    assert loaded["config"]["emulated_devices"] == 2
+    if loaded["config"]["mode"] == "cpu-emulation":
+        assert loaded["config"]["emulated_devices"] == 2
     for row in loaded["lanes"].values():
         assert row["fused_engine_dispatches"] == 1
         assert row["fused_shard_engine_dispatches"] == 1

@@ -1,8 +1,11 @@
 """§Roofline: three-term roofline per (arch x shape) from the dry-run.
 
-  compute term    = HLO_FLOPs / (chips * 197e12 bf16 FLOP/s)
-  memory term     = HLO_bytes / (chips * 819e9 B/s HBM)
-  collective term = collective_bytes / (chips * 50e9 B/s ICI)
+  compute term    = HLO_FLOPs / peak bf16 FLOP/s
+  memory term     = HLO_bytes / peak HBM B/s
+  collective term = collective_bytes / peak ICI B/s per link
+
+Peaks come from ``launch.mesh.chip_peaks(DEVICE_KIND)``: the v5e the
+dry-run meshes describe (a kind without published peaks raises).
 
 HLO_FLOPs/bytes/collective_bytes are the probe-corrected per-device
 totals from results/probes.json (the raw dryrun.json numbers undercount
@@ -19,9 +22,10 @@ from typing import Dict, Optional
 from benchmarks.common import emit
 from repro.configs.base import INPUT_SHAPES
 from repro.configs.registry import get_config, variant_for_shape
-from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
+from repro.launch.mesh import chip_peaks
 
 CHIPS = 256
+DEVICE_KIND = "TPU v5 lite"
 
 
 def model_flops(arch: str, shape_name: str) -> float:
@@ -41,6 +45,7 @@ def model_flops(arch: str, shape_name: str) -> float:
 
 def roofline_terms(rec: Dict, probe: Optional[Dict]) -> Dict:
     """rec: dryrun.json record; probe: probes.json record (or None)."""
+    peaks = chip_peaks(DEVICE_KIND)
     if probe and "flops" in probe:
         flops_dev = probe["flops"]
         bytes_dev = probe["bytes"]
@@ -51,9 +56,9 @@ def roofline_terms(rec: Dict, probe: Optional[Dict]) -> Dict:
         bytes_dev = rec["cost"].get("bytes accessed", 0.0)
         coll_dev = sum(v["bytes"] for v in rec["collectives"].values())
         src = "raw(scan-undercounted)"
-    t_comp = flops_dev / PEAK_FLOPS_BF16
-    t_mem = bytes_dev / HBM_BW
-    t_coll = coll_dev / ICI_BW_PER_LINK
+    t_comp = flops_dev / peaks["flops_bf16"]
+    t_mem = bytes_dev / peaks["hbm_bw"]
+    t_coll = coll_dev / peaks["ici_bw_per_link"]
     dominant = max(("compute", t_comp), ("memory", t_mem),
                    ("collective", t_coll), key=lambda kv: kv[1])[0]
     mf = model_flops(rec["arch"], rec["shape"])

@@ -177,6 +177,8 @@ def main() -> None:
                          "benchmarks/baselines/ and exit non-zero on a "
                          ">2x slowdown ($BENCH_CHECK_FACTOR overrides)")
     args = ap.parse_args()
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
 
     state = {"trained": None}
 

@@ -51,7 +51,7 @@ from repro.core import compression as comp
 from repro.core import cost_model as cm
 from repro.core import resource as ra
 from repro.configs.registry import get_hfl_spec
-from repro.core.hfl import evaluate_in_batches, pad_device_data
+from repro.core.hfl import agg_matmul, evaluate_in_batches, pad_device_data
 from repro.core.local_train import cohort_local_sgd
 from repro.data.partition import FederatedData
 from repro.utils import tree_bytes
@@ -163,7 +163,7 @@ def _flush_edge(edge_params, cohort_params, m, deliver_mask, member_mask,
 
     def agg(e, c):
         flat = c.reshape(c.shape[0], -1)
-        new = wn @ flat + wa * e[m].reshape(-1)
+        new = agg_matmul(wn, flat) + wa * e[m].reshape(-1)
         new = jnp.where(tot > 0, new, e[m].reshape(-1))
         return e.at[m].set(new.reshape(e.shape[1:]).astype(e.dtype))
 
@@ -176,13 +176,13 @@ def _cloud_agg(edge_params, assign, sizes, *, M: int):
     identical op order to ``hfl_global_iteration_core``'s cloud path."""
     onehot = jax.nn.one_hot(assign, M, dtype=jnp.float32)
     w_dev = sizes.astype(jnp.float32)
-    edge_tot = onehot.T @ w_dev
+    edge_tot = agg_matmul(onehot.T, w_dev)
     w = jnp.where(edge_tot > 0, edge_tot, 0.0)
     w = w / jnp.maximum(jnp.sum(w), 1.0)
 
     def agg(e):
         flat = e.reshape(M, -1)
-        return (w @ flat).reshape(e.shape[1:]).astype(e.dtype)
+        return agg_matmul(w, flat).reshape(e.shape[1:]).astype(e.dtype)
 
     return jax.tree.map(agg, edge_params)
 
@@ -195,7 +195,7 @@ def _cloud_agg_compressed(edge_params, global_params, assign, sizes, resid,
     to ``_cloud_agg`` — exact when the codec is lossless). Returns
     ``(new_global, new_edge_resid)``."""
     onehot = jax.nn.one_hot(assign, M, dtype=jnp.float32)
-    edge_tot = onehot.T @ sizes.astype(jnp.float32)
+    edge_tot = agg_matmul(onehot.T, sizes.astype(jnp.float32))
     w = jnp.where(edge_tot > 0, edge_tot, 0.0)
     w = w / jnp.maximum(jnp.sum(w), 1.0)
 
@@ -206,7 +206,8 @@ def _cloud_agg_compressed(edge_params, global_params, assign, sizes, resid,
 
     def agg(g_, d):
         flat = d.reshape(M, -1)
-        return (g_.reshape(-1) + w @ flat).reshape(g_.shape).astype(g_.dtype)
+        return (g_.reshape(-1) + agg_matmul(w, flat)).reshape(
+            g_.shape).astype(g_.dtype)
 
     return jax.tree.map(agg, global_params, dec), new_resid
 

@@ -23,7 +23,10 @@ def pairwise_sq_dists(x: jnp.ndarray, c: jnp.ndarray,
         return pk(x, c)
     xx = jnp.sum(x * x, axis=1, keepdims=True)
     cc = jnp.sum(c * c, axis=1)[None, :]
-    return jnp.maximum(xx + cc - 2.0 * (x @ c.T), 0.0)
+    # full f32 like the kernel: a bf16 pass (the TPU default) would move
+    # near-tie argmins and so the cluster labels
+    xc = jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(xx + cc - 2.0 * xc, 0.0)
 
 
 def _kmeans_pp_init(key, x: jnp.ndarray, k: int,
@@ -62,7 +65,7 @@ def kmeans(key, x: jnp.ndarray, k: int, iters: int = 50,
         lab = jnp.argmin(d, axis=1)
         oh = jax.nn.one_hot(lab, k, dtype=jnp.float32)       # (N, k)
         counts = oh.sum(0)
-        sums = oh.T @ x
+        sums = jnp.matmul(oh.T, x, precision=jax.lax.Precision.HIGHEST)
         new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts, 1)[:, None],
                         centers)
         return new, None

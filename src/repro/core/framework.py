@@ -181,6 +181,8 @@ class HFLFramework:
         self._setup_scheduler(k_mini, k_cluster)
         self._setup_assigner(drl_params)
         self.history: List[Dict] = []
+        self.last_sched: Optional[np.ndarray] = None
+        self.last_assign: Optional[np.ndarray] = None
 
     # ------------------------------------------------------------ setup
 
@@ -244,6 +246,7 @@ class HFLFramework:
         assign, _ = self.assigner.assign(pop, sched, self.rng)
         assign = np.asarray(assign)
         assign_latency = time.perf_counter() - t0
+        self.last_sched, self.last_assign = sched, assign
         H = len(sched)
 
         if self.cfg.engine == "sequential":

@@ -30,6 +30,15 @@ import numpy as np
 from repro.core.local_train import cohort_local_sgd
 from repro.data.partition import FederatedData
 
+def agg_matmul(a, b):
+    """``a @ b`` at full f32 precision on every backend.
+
+    Aggregation contracts parameters and integer data sizes (D_n up to
+    700), neither of which survives a bf16 pass, and one bf16 pass is
+    what an f32 matmul gets on TPU by default (the CPU computes f32
+    either way)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
 
 def pad_device_data(fed: FederatedData, Dmax: Optional[int] = None):
     """-> X (N, Dmax, ...), y (N, Dmax), mask (N, Dmax).
@@ -85,7 +94,7 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
     H = sizes.shape[0]
     onehot = jax.nn.one_hot(assign, M, dtype=jnp.float32)      # (H, M)
     w_dev = sizes.astype(jnp.float32)                          # D_n
-    edge_tot = onehot.T @ w_dev                                # (M,) D_{N_m}
+    edge_tot = agg_matmul(onehot.T, w_dev)                     # (M,) D_{N_m}
     has_dev = edge_tot > 0
 
     if agg_kernel:
@@ -109,14 +118,14 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
             / jnp.maximum(edge_tot, 1.0)[:, None]
         w_cloud = jnp.where(has_dev, edge_tot, 0.0)
         w_cloud = w_cloud / jnp.maximum(jnp.sum(w_cloud), 1.0)
-        edge_aggregate = lambda flat: w_edge @ flat           # noqa: E731
-        cloud_aggregate = lambda flat: w_cloud @ flat         # noqa: E731
+        edge_aggregate = functools.partial(agg_matmul, w_edge)
+        cloud_aggregate = functools.partial(agg_matmul, w_cloud)
         if compress:
             # einsum decode-aggregate oracle: dense decode, then matmul
-            edge_dec_aggregate = lambda sc, q: w_edge @ (     # noqa: E731
-                comp.decode_rows(codec, q, sc))
-            cloud_dec_aggregate = lambda sc, q: w_cloud @ (   # noqa: E731
-                comp.decode_rows(codec, q, sc))
+            edge_dec_aggregate = lambda sc, q: agg_matmul(   # noqa: E731
+                w_edge, comp.decode_rows(codec, q, sc))
+            cloud_dec_aggregate = lambda sc, q: agg_matmul(  # noqa: E731
+                w_cloud, comp.decode_rows(codec, q, sc))
 
     # edge models start from the global model
     edge_params = jax.tree.map(

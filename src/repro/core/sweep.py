@@ -31,7 +31,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.core import compression as comp
@@ -301,8 +300,8 @@ def sweep_round_sharded(apply_fn, sp: cm.SystemParams, params_b, u_b, D_b,
         in_specs = in_specs + (lane, lane, lane)
         out_specs = (lane, (lane, lane), (lane, lane))
         args = args + (codec_state_b[0], codec_state_b[1], codec_keys_b)
-    sharded = shard_map(block, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    sharded = jax.shard_map(block, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     return sharded(*args)
 
 
@@ -527,11 +526,11 @@ def sweep_scan_sharded(apply_fn, sp: cm.SystemParams, sp_assign, params_b,
         in_specs = in_specs + (lane, lane, rep)
         carry_specs = carry_specs + (lane, rep)
         args = args + (codec_state_b, codec_base_b, codec_r0)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         block, mesh=mesh,
         in_specs=in_specs,
         out_specs=(carry_specs, (rlane, rlane, rlane)),
-        check_rep=False)
+        check_vma=False)
     return sharded(*args)
 
 

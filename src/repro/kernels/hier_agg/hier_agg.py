@@ -40,6 +40,10 @@ from jax.experimental import pallas as pl
 
 BP = 512
 SUB = 8      # f32 sublane multiple
+# The panel multiplies parameters (and decode scales), which a bf16 pass
+# would round to ~3 digits: ask for full f32 rather than rely on the
+# backend's default for f32 operands.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _pad2(a, s0, s1):
@@ -55,7 +59,8 @@ def _kernel_batched(w_ref, d_ref, out_ref):
     w = w_ref[0].astype(jnp.float32)              # (Mp, Hp)
     d = d_ref[0].astype(jnp.float32)              # (Hp, BP)
     out_ref[0] = jax.lax.dot_general(
-        w, d, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        w, d, (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -104,7 +109,8 @@ def _masked_kernel_batched(m_ref, s_ref, d_ref, out_ref):
     w = w / jnp.maximum(tot, 1.0)
     d = d_ref[0].astype(jnp.float32)              # (Hp, BP)
     out_ref[0] = jax.lax.dot_general(
-        w, d, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        w, d, (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -167,7 +173,8 @@ def _masked_dec_kernel_batched(m_ref, s_ref, sc_ref, q_ref, out_ref):
     w = (w / jnp.maximum(tot, 1.0)) * sc[0][None, :]
     q = q_ref[0].astype(jnp.float32)              # (Hp, BP) wire dtype
     out_ref[0] = jax.lax.dot_general(
-        w, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        w, q, (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def _q_sublane(dtype) -> int:

@@ -39,7 +39,8 @@ def _kernel(x_ref, c_ref, out_ref, acc_ref, *, n_p_blocks: int):
     xx = jnp.sum(x * x, axis=1, keepdims=True)   # (BN, 1)
     cc = jnp.sum(c * c, axis=1)[None, :]         # (1, BK)
     acc_ref[...] += xx + cc - 2.0 * jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, c, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
     @pl.when(pi == n_p_blocks - 1)
     def _done():

@@ -25,7 +25,7 @@ import argparse
 import dataclasses
 import json
 import os
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 from repro.checkpoint import ckpt
 from repro.core import compression as comp
@@ -33,15 +33,19 @@ from repro.core import cost_model as cm
 from repro.core.async_engine import AsyncConfig, AsyncHFLEngine
 from repro.core.traffic import TrafficGenerator, TrafficParams
 from repro.data import make_dataset, partition_noniid
+from repro.utils import enable_compile_cache
 
 
 def build_world(n_devices: int, n_edges: int, n_train: int, n_test: int,
                 seed: int, L: Optional[int] = None,
-                Q: Optional[int] = None):
+                Q: Optional[int] = None,
+                d_range: Tuple[int, int] = (20, 40)):
     """Population + synthetic non-IID federated dataset (quickstart
-    recipe) sized for a streaming run."""
+    recipe) sized for a streaming run. ``d_range`` is the per-device
+    data size D_n range used both to price devices (eqs. (4)-(5)) and to
+    partition the data; the paper's setting is ``(400, 700)``."""
     sp = cm.SystemParams(n_devices=n_devices, n_edges=n_edges,
-                         d_range=(50, 90))
+                         d_range=tuple(d_range))
     if L is not None:
         sp = dataclasses.replace(sp, L=L)
     if Q is not None:
@@ -50,7 +54,7 @@ def build_world(n_devices: int, n_edges: int, n_train: int, n_test: int,
     X, y, Xt, yt = make_dataset("fmnist_syn", n_train=n_train,
                                 n_test=n_test, seed=seed)
     fed = partition_noniid(X, y, Xt, yt, n_devices=n_devices,
-                           size_range=(20, 40), seed=seed)
+                           size_range=sp.d_range, seed=seed)
     return sp, pop, fed
 
 
@@ -86,7 +90,8 @@ def run_serve(n_devices: int = 40, n_edges: int = 5, H: int = 20,
               n_train: int = 2000, n_test: int = 500,
               alloc_steps: int = 100, L: Optional[int] = None,
               Q: Optional[int] = None, codec: str = "none",
-              topk_frac: float = 0.05, log=print) -> Dict:
+              topk_frac: float = 0.05,
+              d_range: Tuple[int, int] = (20, 40), log=print) -> Dict:
     """Stream ``rounds`` async HFL rounds; returns the engine summary.
 
     Importable/testable core of the CLI: ``log`` receives one JSON line
@@ -96,7 +101,7 @@ def run_serve(n_devices: int = 40, n_edges: int = 5, H: int = 20,
     entry point).
     """
     sp, pop, fed = build_world(n_devices, n_edges, n_train, n_test, seed,
-                               L=L, Q=Q)
+                               L=L, Q=Q, d_range=d_range)
     trace = build_trace(traffic, n_devices, seed)
     cfg = AsyncConfig(H=H, scheduler=scheduler, buffer_size=buffer_size,
                       staleness_exp=staleness_exp, seed=seed,
@@ -150,6 +155,7 @@ def main() -> None:
     ap.add_argument("--topk-frac", type=float, default=0.05,
                     help="kept fraction per tensor for --codec topk")
     args = ap.parse_args()
+    enable_compile_cache()
 
     kw = dict(n_devices=args.devices, n_edges=args.edges, H=args.H,
               rounds=args.rounds, scheduler=args.scheduler,

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+import os
 from typing import Any, Iterable
 
 import jax
@@ -117,6 +118,27 @@ def stable_hash(s: str) -> int:
     return h
 
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here. Otherwise the cache goes to the fixed
+    ``<repo>/.jax_cache``, so later processes find what earlier ones
+    compiled. Call from ``main``, never at import.
+    Returns the cache directory in use.
+    """
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    cache_dir = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
+
+
 def forced_device_env(n_devices: int, pythonpath=()) -> dict:
     """Child-process env for N emulated host devices.
 
@@ -128,8 +150,6 @@ def forced_device_env(n_devices: int, pythonpath=()) -> dict:
     -wins would otherwise depend on the caller's environment), the CPU
     platform is pinned, and ``pythonpath`` entries are prepended.
     """
-    import os
-
     env = os.environ.copy()
     kept = [f for f in env.get("XLA_FLAGS", "").split()
             if not f.startswith("--xla_force_host_platform_device_count")]

@@ -68,6 +68,57 @@ def test_build_trace_presets():
     assert build_trace("always-on", 8, seed=0).init_up.all()
 
 
+def test_build_world_prices_and_partitions_one_data_size_range():
+    """The cost model's D_n and the partitioned data come from the same
+    range (they used to be priced at 50-90 but partitioned at 20-40)."""
+    from repro.launch.serve import build_world
+    sp, pop, fed = build_world(10, 3, 300, 120, 0, d_range=(15, 25))
+    assert sp.d_range == (15, 25)
+    D = np.asarray(pop.D)
+    assert D.min() >= 15 and D.max() <= 25
+    assert min(fed.sizes) >= 15 and max(fed.sizes) <= 25
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    from repro.launch.mesh import chip_peaks
+    v5e = chip_peaks("TPU v5 lite")
+    assert v5e["flops_bf16"] == 197e12 and v5e["hbm_bw"] == 819e9
+    assert "TPU v5e" in v5e["source"]
+    with pytest.raises(ValueError):
+        chip_peaks("cpu")
+
+
+def test_compile_cache_respects_env_else_repo_dir(monkeypatch):
+    import jax
+    from repro.utils import REPO_ROOT, enable_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(REPO_ROOT, ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_chip_smoke_refuses_the_cpu():
+    """The chip smoke never falls back to the CPU: it exits 2 at the
+    device check and prints no result line."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=root,
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 2
+    assert proc.stdout == ""
+    assert "device check failed" in proc.stderr
+
+
 # ------------------------------------------------------------- dryrun
 
 def test_shape_bytes_parses_dtype_and_dims():

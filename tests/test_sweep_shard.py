@@ -248,6 +248,11 @@ def test_sweep_mesh_shape_and_validation():
 
     mesh = sweep_mesh()
     assert mesh.axis_names == ("lane",)
+    # Auto axes: lane-sharded arrays keep their sharding out of the type,
+    # so the CNN's pooling reshape stays legal inside the sweep
+    from jax.sharding import AxisType
+    assert mesh.axis_types == (AxisType.Auto,)
+    assert set(make_debug_mesh().axis_types) == {AxisType.Auto}
     with pytest.raises(ValueError):
         sweep_mesh(10_000)
     assert pad_lanes(5, 8) == 8
