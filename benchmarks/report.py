@@ -41,31 +41,13 @@ def dryrun_table(recs, mesh):
     return "\n".join(out)
 
 
-def roofline_table(rows):
-    out = ["| arch | shape | compute s | memory s | collective s | "
-           "dominant | useful ratio | fits 16G | src |",
-           "|---|---|---|---|---|---|---|---|---|"]
-    order = {"train_4k": 0, "prefill_32k": 1, "decode_32k": 2, "long_500k": 3}
-    rows = sorted(rows, key=lambda r: (r["arch"], order.get(r["shape"], 9)))
-    for t in rows:
-        out.append(
-            f"| {t['arch']} | {t['shape']} | {t['t_compute_s']:.4f} | "
-            f"{t['t_memory_s']:.4f} | {t['t_collective_s']:.4f} | "
-            f"**{t['dominant']}** | {t['useful_ratio']:.2f} | "
-            f"{'yes' if t['fits_16g'] else 'NO'} | {t['source']} |")
-    return "\n".join(out)
-
-
 def main():
     dr = load("results/dryrun.json") or []
-    rl = load("results/roofline.json") or []
     print("<!-- generated by benchmarks/report.py -->\n")
     print("## Dry-run records\n")
     print(dryrun_table(dr, "16x16"))
     print()
     print(dryrun_table(dr, "2x16x16"))
-    print("\n## Roofline (single-pod 16x16, per device)\n")
-    print(roofline_table(rl))
 
 
 if __name__ == "__main__":

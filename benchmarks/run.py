@@ -1,4 +1,4 @@
-"""Benchmark harness — one module per paper table/figure + roofline.
+"""Benchmark harness — one module per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--fast] [--smoke|--perf] [--only NAME]
 
@@ -157,7 +157,7 @@ def write_step_summary(rows, total_s: float, path: str | None = None) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    help="table2|fig34|fig5|fig6|fig7|kernels|roofline|"
+                    help="table2|fig34|fig5|fig6|fig7|"
                          "engine|hfel|hier_agg|drl_train|sweep_shard|"
                          "sweep_fused|schedule_scale|async_engine|"
                          "comm_compress|model_zoo")
@@ -182,10 +182,6 @@ def main() -> None:
 
     state = {"trained": None}
 
-    def run_kernels():
-        from benchmarks import kernels_bench
-        kernels_bench.run()
-
     def run_table2():
         from benchmarks import table2_clustering
         table2_clustering.run()
@@ -209,10 +205,6 @@ def main() -> None:
         from benchmarks import fig7_framework
         fig7_framework.run(h_values=(10, 20) if args.fast else (10, 20, 40),
                            max_iters=4 if args.fast else 12)
-
-    def run_roofline():
-        from benchmarks import roofline
-        roofline.run()
 
     def _perf_bench(mod, name):
         if args.smoke:
@@ -265,13 +257,11 @@ def main() -> None:
     # fig6 reuses fig5's trained D3QN when both are selected, so order
     # matters: fig5 before fig6
     suites = [
-        ("kernels", run_kernels),
         ("table2", run_table2),
         ("fig5", run_fig5),
         ("fig6", run_fig6),
         ("fig34", run_fig34),
         ("fig7", run_fig7),
-        ("roofline", run_roofline),
         ("engine", run_engine),
         ("hfel", run_hfel),
         ("hier_agg", run_hier_agg),

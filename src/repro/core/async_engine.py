@@ -46,6 +46,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import compression as comp
 from repro.core import cost_model as cm
@@ -298,30 +299,41 @@ class AsyncHFLEngine:
     # ------------------------------------------------------------ round
 
     def step_round(self, collect_eval: bool = True) -> Dict:
+        """One cloud round. Host spans ``async.schedule``,
+        ``async.assign``, ``async.price``, ``async.dispatch`` (each
+        ``_train_dispatched`` call), ``async.flush`` (each
+        ``_flush_edge`` call), ``async.cloud_agg`` and ``async.eval``,
+        each with ``round=`` the record's round, go into whatever
+        ``jax.profiler`` trace is running; the record counts
+        ``n_dispatches`` and ``lanes_dispatched``."""
         sp, pop, cfg = self.sp, self.pop, self.cfg
         M, Q = pop.n_edges, sp.Q
         t0 = self.t
+        rnd = self.round + 1
 
-        sched = np.asarray(self.scheduler.schedule(self.rng))
-        assign_np, _ = self.assigner.assign(pop, sched, self.rng)
-        assign_np = np.asarray(assign_np)
+        with TraceAnnotation("async.schedule", round=rnd):
+            sched = np.asarray(self.scheduler.schedule(self.rng))
+        with TraceAnnotation("async.assign", round=rnd):
+            assign_np, _ = self.assigner.assign(pop, sched, self.rng)
+            assign_np = np.asarray(assign_np)
         self.last_sched, self.last_assign = sched, assign_np
         H = len(sched)
         assign_j = jnp.asarray(assign_np, jnp.int32)
         sizes = pop.D[sched]
 
-        b, f, tc, ec, T_cl, E_cl = _alloc_and_price(
-            self.sp_round, pop.u[sched], pop.D[sched], pop.p[sched],
-            pop.g[sched], pop.g_cloud, pop.B_m, assign_j, M=M,
-            alloc_steps=cfg.alloc_steps)
-        self.last_alloc = (b, f, tc, ec)
-        ec_h = np.asarray(ec, np.float64)
-        T_cl_h = np.asarray(T_cl, np.float64)
-        lat = (np.asarray(tc, np.float64)
-               * self.trace.latency_scale[sched])
+        with TraceAnnotation("async.price", round=rnd):
+            b, f, tc, ec, T_cl, E_cl = _alloc_and_price(
+                self.sp_round, pop.u[sched], pop.D[sched], pop.p[sched],
+                pop.g[sched], pop.g_cloud, pop.B_m, assign_j, M=M,
+                alloc_steps=cfg.alloc_steps)
+            self.last_alloc = (b, f, tc, ec)
+            ec_h = np.asarray(ec, np.float64)
+            T_cl_h = np.asarray(T_cl, np.float64)
+            lat = (np.asarray(tc, np.float64)
+                   * self.trace.latency_scale[sched])
 
         codec_on = self.codec.active
-        cohort_resid, n_disp = None, 0
+        cohort_resid = None
         if codec_on:
             cohort_resid = jax.tree.map(lambda r_: r_[sched],
                                         self.dev_resid)
@@ -350,7 +362,8 @@ class AsyncHFLEngine:
             if len(members[m]) == 0:                 # cloud hop only
                 flushes[m] = Q
         stats = {"n_agg": 0, "n_stale": 0, "max_stale": 0,
-                 "n_aborted": 0, "wasted_j": 0.0}
+                 "n_aborted": 0, "wasted_j": 0.0,
+                 "n_dispatches": 0, "lanes_dispatched": 0}
 
         heap: list = []
         seq = 0
@@ -370,7 +383,7 @@ class AsyncHFLEngine:
                 push(float(tog_rows[s][i]), "toggle", s)
 
         def dispatch(slots, t):
-            nonlocal cohort_params, cohort_resid, n_disp, next_task
+            nonlocal cohort_params, cohort_resid, next_task
             slots = [s for s in slots
                      if up[s] and not delivered[s] and task_id[s] < 0
                      and flushes[assign_np[s]] < Q]
@@ -378,17 +391,24 @@ class AsyncHFLEngine:
                 return
             dmask = np.zeros(H, bool)
             dmask[slots] = True
-            if codec_on:
-                cohort_params, cohort_resid = _train_dispatched_compressed(
-                    self.apply_fn, cohort_params, edge_params, assign_j,
-                    jnp.asarray(dmask), Xc, yc, mc, cfg.lr, cohort_resid,
-                    jax.random.fold_in(k_disp, n_disp), L=sp.L,
-                    codec=self.codec)
-                n_disp += 1
-            else:
-                cohort_params = _train_dispatched(
-                    self.apply_fn, cohort_params, edge_params, assign_j,
-                    jnp.asarray(dmask), Xc, yc, mc, cfg.lr, L=sp.L)
+            # every dispatch trains all H lanes; len(slots) of them count
+            with TraceAnnotation("async.dispatch", round=rnd):
+                if codec_on:
+                    cohort_params, cohort_resid = \
+                        _train_dispatched_compressed(
+                            self.apply_fn, cohort_params, edge_params,
+                            assign_j, jnp.asarray(dmask), Xc, yc, mc,
+                            cfg.lr, cohort_resid,
+                            jax.random.fold_in(k_disp,
+                                               stats["n_dispatches"]),
+                            L=sp.L, codec=self.codec)
+                else:
+                    cohort_params = _train_dispatched(
+                        self.apply_fn, cohort_params, edge_params,
+                        assign_j, jnp.asarray(dmask), Xc, yc, mc, cfg.lr,
+                        L=sp.L)
+            stats["n_dispatches"] += 1
+            stats["lanes_dispatched"] += len(slots)
             for s in slots:
                 start_ver[s] = edge_ver[assign_np[s]]
                 task_id[s] = next_task
@@ -407,11 +427,12 @@ class AsyncHFLEngine:
             mem_mask = np.zeros(H, bool)
             mem_mask[mem] = True
             stal = np.where(del_mask, edge_ver[m] - start_ver, 0)
-            edge_params = _flush_edge(
-                edge_params, cohort_params, jnp.int32(m),
-                jnp.asarray(del_mask), jnp.asarray(mem_mask),
-                sizes, jnp.asarray(stal, jnp.float32),
-                jnp.float32(cfg.staleness_exp))
+            with TraceAnnotation("async.flush", round=rnd):
+                edge_params = _flush_edge(
+                    edge_params, cohort_params, jnp.int32(m),
+                    jnp.asarray(del_mask), jnp.asarray(mem_mask),
+                    sizes, jnp.asarray(stal, jnp.float32),
+                    jnp.float32(cfg.staleness_exp))
             d_slots = np.flatnonzero(del_mask)
             edge_energy[m] += float(ec_h[d_slots].sum())
             stats["n_agg"] += len(d_slots)
@@ -488,23 +509,25 @@ class AsyncHFLEngine:
         T_m = (edge_finish - t0) + T_cl_h
         T_round = float(T_m.max()) if M else 0.0
         E_round = float(edge_energy.sum() + np.asarray(E_cl).sum())
-        if codec_on:
-            self.model_params, self.edge_resid = _cloud_agg_compressed(
-                edge_params, self.model_params, assign_j, sizes,
-                self.edge_resid, k_cloud, M=M, codec=self.codec)
-            self.dev_resid = jax.tree.map(
-                lambda full, r_: full.at[jnp.asarray(sched)].set(r_),
-                self.dev_resid, cohort_resid)
-        else:
-            self.model_params = _cloud_agg(edge_params, assign_j, sizes,
-                                           M=M)
+        with TraceAnnotation("async.cloud_agg", round=rnd):
+            if codec_on:
+                self.model_params, self.edge_resid = _cloud_agg_compressed(
+                    edge_params, self.model_params, assign_j, sizes,
+                    self.edge_resid, k_cloud, M=M, codec=self.codec)
+                self.dev_resid = jax.tree.map(
+                    lambda full, r_: full.at[jnp.asarray(sched)].set(r_),
+                    self.dev_resid, cohort_resid)
+            else:
+                self.model_params = _cloud_agg(edge_params, assign_j, sizes,
+                                               M=M)
         self.t = t0 + T_round
         self.round += 1
 
         acc = None
         if collect_eval:
-            acc = evaluate_in_batches(self.apply_fn, self.model_params,
-                                      self.fed.X_test, self.fed.y_test)
+            with TraceAnnotation("async.eval", round=rnd):
+                acc = evaluate_in_batches(self.apply_fn, self.model_params,
+                                          self.fed.X_test, self.fed.y_test)
         rec = {"round": self.round, "t": self.t, "acc": acc,
                "T_i": T_round, "E_i": E_round,
                "obj_i": E_round + sp.lam * T_round,
@@ -514,6 +537,8 @@ class AsyncHFLEngine:
                "n_aborted": stats["n_aborted"],
                "wasted_j": stats["wasted_j"],
                "forced_flushes": forced,
+               "n_dispatches": stats["n_dispatches"],
+               "lanes_dispatched": stats["lanes_dispatched"],
                "msg_bits": cm.round_msg_bits(self.sp, stats["n_agg"], M,
                                              msg_bits=self.uplink_bits),
                "uplink_bytes": float(

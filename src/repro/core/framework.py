@@ -37,6 +37,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import compression as comp
 from repro.core import cost_model as cm
@@ -77,16 +78,19 @@ def round_step_core(apply_fn, sp: cm.SystemParams, params, u, D, p, g,
     becomes ``(new_params, (new_dev_resid, new_edge_resid), aux)``.
     """
     H = assign.shape[0]
-    edge_mask = assign[None, :] == jnp.arange(M)[:, None]       # (M, H)
-    res = ra.allocate_batch(
-        sp,
-        jnp.broadcast_to(u, (M, H)), jnp.broadcast_to(D, (M, H)),
-        jnp.broadcast_to(p, (M, H)), g.T, B_m, edge_mask,
-        steps=alloc_steps)
-    b, f = ra.select_device_allocation(res, assign)             # (H,) each
-    g_sel = g[jnp.arange(H), assign]
-    T_i, E_i, T_m, E_m = cm.round_cost_gathered(
-        sp, u, D, p, g_sel, g_cloud, assign, b, f, M)
+    # the plan and its price, problem (27); training and aggregation
+    # carry the scopes local_train / aggregate (hfl.py, local_train.py)
+    with jax.named_scope("allocate"):
+        edge_mask = assign[None, :] == jnp.arange(M)[:, None]   # (M, H)
+        res = ra.allocate_batch(
+            sp,
+            jnp.broadcast_to(u, (M, H)), jnp.broadcast_to(D, (M, H)),
+            jnp.broadcast_to(p, (M, H)), g.T, B_m, edge_mask,
+            steps=alloc_steps)
+        b, f = ra.select_device_allocation(res, assign)         # (H,) each
+        g_sel = g[jnp.arange(H), assign]
+        T_i, E_i, T_m, E_m = cm.round_cost_gathered(
+            sp, u, D, p, g_sel, g_cloud, assign, b, f, M)
     if codec is not None and codec.active:
         dev_resid, edge_resid = codec_state
         new_params, dev_resid, edge_resid = hfl_global_iteration_core(
@@ -177,6 +181,8 @@ class HFLFramework:
                                 pop.n_edges))
 
         self.X, self.y, self.mask = pad_device_data(fed)
+        # (N,) valid samples per device, for the pad share
+        self._n_valid = np.asarray(self.mask).sum(axis=1).astype(np.int64)
         self.clustering_stats: Dict = {}
         self._setup_scheduler(k_mini, k_cluster)
         self._setup_assigner(drl_params)
@@ -240,58 +246,81 @@ class HFLFramework:
     # ------------------------------------------------------------- round
 
     def run_round(self, i: int) -> Dict:
+        """One global iteration. Host spans ``hfl.schedule``,
+        ``hfl.assign``, ``hfl.cohort``, ``hfl.round_step``, ``hfl.eval``
+        and ``hfl.record`` (each with ``round=i``) go into whatever
+        ``jax.profiler`` trace is running; the record counts
+        ``assign_latency_s`` and ``pad_share``."""
         sp, pop = self.sp, self.pop
-        sched = np.asarray(self.scheduler.schedule(self.rng))
-        t0 = time.perf_counter()
-        assign, _ = self.assigner.assign(pop, sched, self.rng)
-        assign = np.asarray(assign)
-        assign_latency = time.perf_counter() - t0
+        with TraceAnnotation("hfl.schedule", round=i):
+            sched = np.asarray(self.scheduler.schedule(self.rng))
+        with TraceAnnotation("hfl.assign", round=i):
+            t0 = time.perf_counter()
+            assign, _ = self.assigner.assign(pop, sched, self.rng)
+            assign = np.asarray(assign)
+            assign_latency = time.perf_counter() - t0
         self.last_sched, self.last_assign = sched, assign
         H = len(sched)
 
         if self.cfg.engine == "sequential":
-            T_i, E_i = self._sequential_alloc_cost_train(sched, assign)
-        elif self.codec.active:
-            dev_resid, edge_resid = self.codec_state
-            cohort_resid = jax.tree.map(lambda r: r[sched], dev_resid)
-            (self.model_params, (cohort_resid, edge_resid),
-             (T_i, E_i, _, _, _, _)) = round_step(
-                self.apply_fn, self.sp_round, self.model_params,
-                pop.u[sched], pop.D[sched], pop.p[sched], pop.g[sched],
-                pop.g_cloud, pop.B_m,
-                self.X[sched], self.y[sched], self.mask[sched],
-                pop.D[sched], jnp.asarray(assign), self.cfg.lr,
-                M=pop.n_edges, L=sp.L, Q=sp.Q,
-                alloc_steps=self.cfg.alloc_steps,
-                agg_kernel=self.cfg.agg_kernel, codec=self.codec,
-                codec_state=(cohort_resid, edge_resid),
-                codec_key=comp.round_key(self.codec, self.cfg.seed, i))
-            self.codec_state = (
-                jax.tree.map(lambda full, nr: full.at[sched].set(nr),
-                             dev_resid, cohort_resid),
-                edge_resid)
+            with TraceAnnotation("hfl.round_step", round=i):
+                T_i, E_i = self._sequential_alloc_cost_train(sched, assign)
         else:
-            self.model_params, (T_i, E_i, _, _, _, _) = round_step(
-                self.apply_fn, sp, self.model_params,
-                pop.u[sched], pop.D[sched], pop.p[sched], pop.g[sched],
-                pop.g_cloud, pop.B_m,
-                self.X[sched], self.y[sched], self.mask[sched],
-                pop.D[sched], jnp.asarray(assign), self.cfg.lr,
-                M=pop.n_edges, L=sp.L, Q=sp.Q,
-                alloc_steps=self.cfg.alloc_steps,
-                agg_kernel=self.cfg.agg_kernel)
+            # gathers of the cohort's rows and the assignment's upload
+            with TraceAnnotation("hfl.cohort", round=i):
+                D = pop.D[sched]
+                cohort = (pop.u[sched], D, pop.p[sched], pop.g[sched],
+                          pop.g_cloud, pop.B_m, self.X[sched],
+                          self.y[sched], self.mask[sched], D,
+                          jnp.asarray(assign))
+                if self.codec.active:
+                    dev_resid, edge_resid = self.codec_state
+                    cohort_resid = jax.tree.map(lambda r: r[sched],
+                                                dev_resid)
+            # the round program's dispatch; it runs on while the host
+            # goes on to the evaluation, whose first chunk waits for it
+            with TraceAnnotation("hfl.round_step", round=i):
+                if self.codec.active:
+                    (self.model_params, (cohort_resid, edge_resid),
+                     (T_i, E_i, _, _, _, _)) = round_step(
+                        self.apply_fn, self.sp_round, self.model_params,
+                        *cohort, self.cfg.lr,
+                        M=pop.n_edges, L=sp.L, Q=sp.Q,
+                        alloc_steps=self.cfg.alloc_steps,
+                        agg_kernel=self.cfg.agg_kernel, codec=self.codec,
+                        codec_state=(cohort_resid, edge_resid),
+                        codec_key=comp.round_key(self.codec, self.cfg.seed,
+                                                 i))
+                    self.codec_state = (
+                        jax.tree.map(lambda full, nr: full.at[sched].set(nr),
+                                     dev_resid, cohort_resid),
+                        edge_resid)
+                else:
+                    self.model_params, (T_i, E_i, _, _, _, _) = round_step(
+                        self.apply_fn, sp, self.model_params,
+                        *cohort, self.cfg.lr,
+                        M=pop.n_edges, L=sp.L, Q=sp.Q,
+                        alloc_steps=self.cfg.alloc_steps,
+                        agg_kernel=self.cfg.agg_kernel)
 
-        acc = self.spec.eval_fn(self.model_params,
-                                self.fed.X_test, self.fed.y_test)
-        msg_bits = cm.round_msg_bits(self.sp, sp.Q * H, pop.n_edges,
-                                     msg_bits=self.uplink_bits)
-        rec = {"iter": i, "acc": acc, "T_i": float(T_i), "E_i": float(E_i),
-               "obj_i": float(E_i + sp.lam * T_i),
-               "msg_bits": float(msg_bits),
-               "uplink_bytes": float(sp.Q * H * self.uplink_bits / 8),
-               "codec": self.codec.codec,
-               "assign_latency_s": assign_latency,
-               "H": H}
+        with TraceAnnotation("hfl.eval", round=i):
+            acc = self.spec.eval_fn(self.model_params,
+                                    self.fed.X_test, self.fed.y_test)
+        with TraceAnnotation("hfl.record", round=i):
+            msg_bits = cm.round_msg_bits(self.sp, sp.Q * H, pop.n_edges,
+                                         msg_bits=self.uplink_bits)
+            rec = {"iter": i, "acc": acc, "T_i": float(T_i),
+                   "E_i": float(E_i),
+                   "obj_i": float(E_i + sp.lam * T_i),
+                   "msg_bits": float(msg_bits),
+                   "uplink_bytes": float(sp.Q * H * self.uplink_bits / 8),
+                   "codec": self.codec.codec,
+                   "assign_latency_s": assign_latency,
+                   # share of the cohort's (H, Dmax) sample slots that are
+                   # padding: trained on, masked out of the loss
+                   "pad_share": 1.0 - int(self._n_valid[sched].sum())
+                                 / (H * self.mask.shape[1]),
+                   "H": H}
         self.history.append(rec)
         return rec
 

@@ -26,6 +26,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.local_train import cohort_local_sgd
 from repro.data.partition import FederatedData
@@ -92,40 +93,44 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
     if compress:
         from repro.core import compression as comp
     H = sizes.shape[0]
-    onehot = jax.nn.one_hot(assign, M, dtype=jnp.float32)      # (H, M)
-    w_dev = sizes.astype(jnp.float32)                          # D_n
-    edge_tot = agg_matmul(onehot.T, w_dev)                     # (M,) D_{N_m}
-    has_dev = edge_tot > 0
+    # named scopes: local_train (cohort_local_sgd and the pull of edge
+    # models) and aggregate (eqs. (2)-(3), their weights, the codec)
+    with jax.named_scope("aggregate"):
+        onehot = jax.nn.one_hot(assign, M, dtype=jnp.float32)  # (H, M)
+        w_dev = sizes.astype(jnp.float32)                      # D_n
+        edge_tot = agg_matmul(onehot.T, w_dev)                 # (M,) D_{N_m}
+        has_dev = edge_tot > 0
 
-    if agg_kernel:
-        from repro.kernels.hier_agg.ops import (masked_aggregate,
-                                                masked_decode_aggregate)
-        # eq. (2): panel built in-kernel from membership rows + sizes
-        edge_aggregate = functools.partial(masked_aggregate, onehot.T, w_dev)
-        # eq. (3) = the same kernel with an all-ones (1, M) mask over the
-        # per-edge cohort sizes D_{N_m} (empty edges weigh 0 already)
-        cloud_aggregate = lambda flat: masked_aggregate(  # noqa: E731
-            jnp.ones((1, M), jnp.float32), edge_tot, flat)[0]
-        # compression path: scales fold into the in-kernel panel, the
-        # wire-format q streams into the MXU undecoded
-        edge_dec_aggregate = functools.partial(
-            masked_decode_aggregate, onehot.T, w_dev)
-        cloud_dec_aggregate = lambda sc, q: masked_decode_aggregate(  # noqa: E731
-            jnp.ones((1, M), jnp.float32), edge_tot, sc, q)[0]
-    else:
-        # per-edge normalised device weights: (M, H)
-        w_edge = (onehot.T * w_dev[None, :]) \
-            / jnp.maximum(edge_tot, 1.0)[:, None]
-        w_cloud = jnp.where(has_dev, edge_tot, 0.0)
-        w_cloud = w_cloud / jnp.maximum(jnp.sum(w_cloud), 1.0)
-        edge_aggregate = functools.partial(agg_matmul, w_edge)
-        cloud_aggregate = functools.partial(agg_matmul, w_cloud)
-        if compress:
-            # einsum decode-aggregate oracle: dense decode, then matmul
-            edge_dec_aggregate = lambda sc, q: agg_matmul(   # noqa: E731
-                w_edge, comp.decode_rows(codec, q, sc))
-            cloud_dec_aggregate = lambda sc, q: agg_matmul(  # noqa: E731
-                w_cloud, comp.decode_rows(codec, q, sc))
+        if agg_kernel:
+            from repro.kernels.hier_agg.ops import (masked_aggregate,
+                                                    masked_decode_aggregate)
+            # eq. (2): panel built in-kernel from membership rows + sizes
+            edge_aggregate = functools.partial(masked_aggregate, onehot.T,
+                                               w_dev)
+            # eq. (3) = the same kernel with an all-ones (1, M) mask over
+            # the per-edge cohort sizes D_{N_m} (empty edges weigh 0)
+            cloud_aggregate = lambda flat: masked_aggregate(  # noqa: E731
+                jnp.ones((1, M), jnp.float32), edge_tot, flat)[0]
+            # compression path: scales fold into the in-kernel panel, the
+            # wire-format q streams into the MXU undecoded
+            edge_dec_aggregate = functools.partial(
+                masked_decode_aggregate, onehot.T, w_dev)
+            cloud_dec_aggregate = lambda sc, q: masked_decode_aggregate(  # noqa: E731
+                jnp.ones((1, M), jnp.float32), edge_tot, sc, q)[0]
+        else:
+            # per-edge normalised device weights: (M, H)
+            w_edge = (onehot.T * w_dev[None, :]) \
+                / jnp.maximum(edge_tot, 1.0)[:, None]
+            w_cloud = jnp.where(has_dev, edge_tot, 0.0)
+            w_cloud = w_cloud / jnp.maximum(jnp.sum(w_cloud), 1.0)
+            edge_aggregate = functools.partial(agg_matmul, w_edge)
+            cloud_aggregate = functools.partial(agg_matmul, w_cloud)
+            if compress:
+                # einsum decode-aggregate oracle: dense decode, then matmul
+                edge_dec_aggregate = lambda sc, q: agg_matmul(  # noqa: E731
+                    w_edge, comp.decode_rows(codec, q, sc))
+                cloud_dec_aggregate = lambda sc, q: agg_matmul(  # noqa: E731
+                    w_cloud, comp.decode_rows(codec, q, sc))
 
     # edge models start from the global model
     edge_params = jax.tree.map(
@@ -134,8 +139,9 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
     if not compress:
         def edge_iter(edge_params, _):
             # each device pulls its edge's model
-            dev_params = jax.tree.map(lambda e: jnp.take(e, assign, axis=0),
-                                      edge_params)
+            with jax.named_scope("local_train"):
+                dev_params = jax.tree.map(
+                    lambda e: jnp.take(e, assign, axis=0), edge_params)
             dev_params = cohort_local_sgd(apply_fn, dev_params, X, y, mask,
                                           L, lr)
             # (2): weighted average per edge; empty edges keep their model
@@ -145,7 +151,8 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
                 new = edge_aggregate(flat).reshape((M,) + delta.shape[1:])
                 keep = has_dev.reshape((M,) + (1,) * (delta.ndim - 1))
                 return jnp.where(keep, new, old).astype(old.dtype)
-            new_edge = jax.tree.map(agg, dev_params, edge_params)
+            with jax.named_scope("aggregate"):
+                new_edge = jax.tree.map(agg, dev_params, edge_params)
             return new_edge, None
 
         edge_params, _ = jax.lax.scan(edge_iter, edge_params, None, length=Q)
@@ -155,34 +162,38 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
             flat = e.reshape(M, -1)
             return cloud_aggregate(flat).reshape(e.shape[1:]).astype(e.dtype)
 
-        return jax.tree.map(cloud_agg, edge_params)
+        with jax.named_scope("aggregate"):
+            return jax.tree.map(cloud_agg, edge_params)
 
     # ---- compressed path: both uplinks ship encoded deltas; aggregation
     #      runs in delta space (edge' = edge + Σ w·decoded_delta, exactly
     #      eq. (2) for a lossless codec since the weights sum to 1 per
     #      non-empty edge — empty edges get zero weight mass and keep
     #      their model automatically).
-    keys = jax.random.split(codec_key, Q + 1)
+    with jax.named_scope("aggregate"):     # stochastic-rounding keys
+        keys = jax.random.split(codec_key, Q + 1)
 
     def edge_iter_c(carry, k_round):
         edge_params, resid = carry
-        pulled = jax.tree.map(lambda e: jnp.take(e, assign, axis=0),
-                              edge_params)
+        with jax.named_scope("local_train"):
+            pulled = jax.tree.map(lambda e: jnp.take(e, assign, axis=0),
+                                  edge_params)
         trained = cohort_local_sgd(apply_fn, pulled, X, y, mask, L, lr)
         t_leaves, treedef = jax.tree.flatten(trained)
         p_leaves = jax.tree.leaves(pulled)
         r_leaves = jax.tree.leaves(resid)
         e_leaves = jax.tree.leaves(edge_params)
-        ks = jax.random.split(k_round, len(t_leaves))
         new_e, new_r = [], []
-        for t, p_, r, e, k in zip(t_leaves, p_leaves, r_leaves, e_leaves,
-                                  ks):
-            d = (t - p_).reshape(H, -1).astype(jnp.float32)
-            q, sc, nr = comp.encode_leaf(codec, k, d, r.reshape(H, -1))
-            dm = edge_dec_aggregate(sc, q)                    # (M, p)
-            ef = e.reshape(M, -1) + dm
-            new_e.append(ef.reshape(e.shape).astype(e.dtype))
-            new_r.append(nr.reshape(r.shape))
+        with jax.named_scope("aggregate"):
+            ks = jax.random.split(k_round, len(t_leaves))
+            for t, p_, r, e, k in zip(t_leaves, p_leaves, r_leaves,
+                                      e_leaves, ks):
+                d = (t - p_).reshape(H, -1).astype(jnp.float32)
+                q, sc, nr = comp.encode_leaf(codec, k, d, r.reshape(H, -1))
+                dm = edge_dec_aggregate(sc, q)                # (M, p)
+                ef = e.reshape(M, -1) + dm
+                new_e.append(ef.reshape(e.shape).astype(e.dtype))
+                new_r.append(nr.reshape(r.shape))
         return (treedef.unflatten(new_e), treedef.unflatten(new_r)), None
 
     (edge_params, dev_resid), _ = jax.lax.scan(
@@ -192,14 +203,15 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
     e_leaves, treedef = jax.tree.flatten(edge_params)
     g_leaves = jax.tree.leaves(global_params)
     r_leaves = jax.tree.leaves(edge_resid)
-    ks = jax.random.split(keys[Q], len(e_leaves))
     new_g, new_r = [], []
-    for e, g, r, k in zip(e_leaves, g_leaves, r_leaves, ks):
-        d = (e.reshape(M, -1) - g.reshape(1, -1)).astype(jnp.float32)
-        q, sc, nr = comp.encode_leaf(codec, k, d, r.reshape(M, -1))
-        gf = g.reshape(-1) + cloud_dec_aggregate(sc, q)
-        new_g.append(gf.reshape(g.shape).astype(g.dtype))
-        new_r.append(nr.reshape(r.shape))
+    with jax.named_scope("aggregate"):
+        ks = jax.random.split(keys[Q], len(e_leaves))
+        for e, g, r, k in zip(e_leaves, g_leaves, r_leaves, ks):
+            d = (e.reshape(M, -1) - g.reshape(1, -1)).astype(jnp.float32)
+            q, sc, nr = comp.encode_leaf(codec, k, d, r.reshape(M, -1))
+            gf = g.reshape(-1) + cloud_dec_aggregate(sc, q)
+            new_g.append(gf.reshape(g.shape).astype(g.dtype))
+            new_r.append(nr.reshape(r.shape))
     return (treedef.unflatten(new_g), dev_resid, treedef.unflatten(new_r))
 
 
@@ -241,6 +253,9 @@ def evaluate_in_batches(apply_fn, params, X_test, y_test, batch: int = 512):
     to the chunk shape with a validity mask instead of compiling a
     second XLA program per (arch, test-set-size) pair; correct counts
     are integers, so the result is the exact sample-weighted accuracy.
+    Each chunk's host work and its wait are the profiler spans
+    ``eval.upload`` and ``eval.wait``; they carry no round of their own
+    and nest in the engine's ``hfl.eval`` / ``async.eval`` span.
     """
     X_test = np.asarray(X_test)
     y_test = np.asarray(y_test)
@@ -250,14 +265,18 @@ def evaluate_in_batches(apply_fn, params, X_test, y_test, batch: int = 512):
     batch = min(batch, n)
     correct = 0
     for i in range(0, n, batch):
-        Xc, yc = X_test[i:i + batch], y_test[i:i + batch]
-        k = len(yc)
-        valid = np.zeros(batch, np.float32)
-        valid[:k] = 1.0
-        if k < batch:       # pad the ragged tail to the chunk shape
-            Xc = np.concatenate(
-                [Xc, np.zeros((batch - k, *Xc.shape[1:]), Xc.dtype)])
-            yc = np.concatenate([yc, np.zeros(batch - k, yc.dtype)])
-        correct += int(_count_correct(apply_fn, params, jnp.asarray(Xc),
-                                      jnp.asarray(yc), jnp.asarray(valid)))
+        # evaluation: host padding and upload of one test chunk
+        with TraceAnnotation("eval.upload"):
+            Xc, yc = X_test[i:i + batch], y_test[i:i + batch]
+            k = len(yc)
+            valid = np.zeros(batch, np.float32)
+            valid[:k] = 1.0
+            if k < batch:   # pad the ragged tail to the chunk shape
+                Xc = np.concatenate(
+                    [Xc, np.zeros((batch - k, *Xc.shape[1:]), Xc.dtype)])
+                yc = np.concatenate([yc, np.zeros(batch - k, yc.dtype)])
+            chunk = jnp.asarray(Xc), jnp.asarray(yc), jnp.asarray(valid)
+        # evaluation: the chunk's forward pass and the wait for its count
+        with TraceAnnotation("eval.wait"):
+            correct += int(_count_correct(apply_fn, params, *chunk))
     return correct / n

@@ -38,7 +38,10 @@ def cohort_local_sgd(apply_fn: Callable, params_per_dev, X, y, mask,
     """vmap of local_sgd over the device axis.
 
     params_per_dev: pytree with leading device axis; X: (H, Dmax, ...).
+    Its ops carry the named scope ``local_train`` in every program that
+    inlines it (the fused round, the async dispatch, the sweep).
     """
     def fn(p, xx, yy, mm):
         return local_sgd(apply_fn, p, xx, yy, mm, L, lr)
-    return jax.vmap(fn)(params_per_dev, X, y, mask)
+    with jax.named_scope("local_train"):
+        return jax.vmap(fn)(params_per_dev, X, y, mask)
