@@ -442,8 +442,9 @@ def phase_serve(st, ph, state):
     from repro.models.cnn import cnn_init
     from repro.models.spec import cnn_spec
 
-    # the async engine's per-dispatch program trains the whole (H, ...)
-    # cohort under a mask: compile it for the chip before serving
+    # the async engine's per-dispatch program carries the whole (H, ...)
+    # cohort and trains the dispatched lanes in chunks: compile it for
+    # the chip before serving
     f32 = jnp.float32
     params = jax.eval_shape(lambda k: cnn_init(k, (28, 28), 1),
                             jax.random.PRNGKey(0))

@@ -24,10 +24,11 @@ virtual clock:
   buffer anchors on the current edge model. After Q buffer flushes the
   edge uploads to the cloud; the cloud aggregates with the eq.-(3)
   cohort-data-size weights.
-* Device state (dispatched / delivered / aborted) rides the same
-  masked-lane machinery as the PR-4/5 done-masks: one fixed-shape
-  ``(H, ...)`` cohort pytree, updated under boolean masks so every jit
-  re-use hits the same compiled program.
+* Device state (dispatched / delivered / aborted) rides one
+  fixed-shape ``(H, ...)`` cohort pytree. A dispatch trains only the
+  lanes its boolean mask sets, compacted into fixed chunks of
+  ``DISPATCH_CHUNK`` lanes, so every dispatch pattern reuses the same
+  compiled program.
 
 Parity: with the degenerate trace (``AvailabilityTrace.always_on``,
 unit latency scale, no jitter, wait-for-all buffers) the event loop
@@ -85,26 +86,63 @@ def _alloc_and_price(sp, u, D, p, g, g_cloud, B_m, assign, *, M: int,
     return b, f, tc, ec, T_cl, E_cl
 
 
+# Lanes per chunk of a dispatch's training loop: a dispatch of k lanes
+# trains ceil(k / C) * C lanes, C = min(DISPATCH_CHUNK, H). On a TPU v5e
+# a lane costs about the same at 4, 8 or 16 lanes a chunk, so the
+# narrowest of those wastes the fewest padding lanes (PERF.md).
+DISPATCH_CHUNK = 4
+
+
+def dispatch_chunk(H: int) -> int:
+    """Lanes per chunk of a dispatch from an H-lane cohort."""
+    return min(DISPATCH_CHUNK, H)
+
+
+def _train_in_chunks(apply_fn, edge_params, assign, dispatch_mask, X, y,
+                     mask, lr, L, carry, keep):
+    """Train the dispatched lanes, C at a time, into ``(H, ...)`` rows.
+
+    The dispatched lanes are compacted in lane order and padded with the
+    out-of-range index H to whole chunks; ``ceil(k / C)`` chunks run in
+    one ``fori_loop``, so one compiled program serves every dispatch
+    pattern. Chunk ``i`` pulls its lanes' edge models, trains them with
+    ``cohort_local_sgd`` and scatters ``keep(i, lanes, carry, pulled,
+    trained)`` (a pytree shaped like ``carry`` with C rows) back into
+    ``carry``. Padding lanes train a copy of the chunk's first lane and
+    are dropped by the scatter; lanes not dispatched come out bitwise
+    unchanged.
+    """
+    H = dispatch_mask.shape[0]
+    C = dispatch_chunk(H)
+    idx = jnp.nonzero(dispatch_mask, size=-(-H // C) * C, fill_value=H)[0]
+    n = (jnp.sum(dispatch_mask, dtype=jnp.int32) + C - 1) // C
+
+    def chunk(i, carry):
+        part = jax.lax.dynamic_slice(idx, (i * C,), (C,))
+        lanes = jnp.where(part < H, part, part[0])
+        pulled = jax.tree.map(lambda e: jnp.take(e, assign[lanes], axis=0),
+                              edge_params)
+        trained = cohort_local_sgd(apply_fn, pulled, X[lanes], y[lanes],
+                                   mask[lanes], L, lr)
+        rows = keep(i, lanes, carry, pulled, trained)
+        return jax.tree.map(lambda c, r: c.at[part].set(r, mode="drop"),
+                            carry, rows)
+
+    return jax.lax.fori_loop(0, n, chunk, carry)
+
+
 @functools.partial(jax.jit, static_argnames=("apply_fn", "L"))
 def _train_dispatched(apply_fn, cohort_params, edge_params, assign,
                       dispatch_mask, X, y, mask, lr, *, L: int):
     """Pull edge models and run L local GD steps on the dispatched lanes.
 
-    Fixed-shape masked update (PR-4/5 done-mask style): every lane runs
-    through ``cohort_local_sgd``, but only lanes where ``dispatch_mask``
-    is set start from their edge's current model and keep the trained
-    result — so one compiled program serves every dispatch pattern.
+    Only the lanes where ``dispatch_mask`` is set train, from their
+    edge's current model, in fixed chunks (``_train_in_chunks``); the
+    other lanes of ``cohort_params`` are returned as they came.
     """
-    def bmask(leaf):
-        return dispatch_mask.reshape((-1,) + (1,) * (leaf.ndim - 1))
-
-    pulled = jax.tree.map(lambda e: jnp.take(e, assign, axis=0),
-                          edge_params)
-    src = jax.tree.map(lambda c, q: jnp.where(bmask(c), q, c),
-                       cohort_params, pulled)
-    trained = cohort_local_sgd(apply_fn, src, X, y, mask, L, lr)
-    return jax.tree.map(lambda c, t: jnp.where(bmask(c), t, c),
-                        cohort_params, trained)
+    return _train_in_chunks(
+        apply_fn, edge_params, assign, dispatch_mask, X, y, mask, lr, L,
+        cohort_params, lambda i, lanes, carry, pulled, trained: trained)
 
 
 @functools.partial(jax.jit, static_argnames=("apply_fn", "L", "codec"))
@@ -119,25 +157,21 @@ def _train_dispatched_compressed(apply_fn, cohort_params, edge_params,
     weighted flush is linear in the decoded update, so merging the
     reconstruction is exactly merging the wire-format update).
     ``resid``: (H, ...) error-feedback rows for the scheduled cohort —
-    updated only on dispatched lanes, like the params.
+    updated only on dispatched lanes, like the params. Chunk ``i``
+    encodes with ``fold_in(key, i)``.
     """
-    def bmask(leaf):
-        return dispatch_mask.reshape((-1,) + (1,) * (leaf.ndim - 1))
+    def keep(i, lanes, carry, pulled, trained):
+        delta = jax.tree.map(lambda t, q: (t - q).astype(jnp.float32),
+                             trained, pulled)
+        dec, new_resid = comp.encode_decode(
+            codec, jax.random.fold_in(key, i), delta,
+            jax.tree.map(lambda r: r[lanes], carry[1]))
+        recon = jax.tree.map(lambda q, d: (q + d).astype(q.dtype),
+                             pulled, dec)
+        return recon, new_resid
 
-    pulled = jax.tree.map(lambda e: jnp.take(e, assign, axis=0),
-                          edge_params)
-    src = jax.tree.map(lambda c, q: jnp.where(bmask(c), q, c),
-                       cohort_params, pulled)
-    trained = cohort_local_sgd(apply_fn, src, X, y, mask, L, lr)
-    delta = jax.tree.map(lambda t, q: (t - q).astype(jnp.float32),
-                         trained, pulled)
-    dec, new_resid = comp.encode_decode(codec, key, delta, resid)
-    recon = jax.tree.map(lambda q, d: (q + d).astype(q.dtype), pulled, dec)
-    new_cohort = jax.tree.map(lambda c, t: jnp.where(bmask(c), t, c),
-                              cohort_params, recon)
-    new_resid = jax.tree.map(lambda r, nr: jnp.where(bmask(r), nr, r),
-                             resid, new_resid)
-    return new_cohort, new_resid
+    return _train_in_chunks(apply_fn, edge_params, assign, dispatch_mask, X,
+                            y, mask, lr, L, (cohort_params, resid), keep)
 
 
 @jax.jit
@@ -305,7 +339,8 @@ class AsyncHFLEngine:
         ``_flush_edge`` call), ``async.cloud_agg`` and ``async.eval``,
         each with ``round=`` the record's round, go into whatever
         ``jax.profiler`` trace is running; the record counts
-        ``n_dispatches`` and ``lanes_dispatched``."""
+        ``n_dispatches``, ``lanes_dispatched`` and ``lanes_trained``
+        (whole chunks of ``dispatch_chunk(H)`` lanes)."""
         sp, pop, cfg = self.sp, self.pop, self.cfg
         M, Q = pop.n_edges, sp.Q
         t0 = self.t
@@ -363,7 +398,9 @@ class AsyncHFLEngine:
                 flushes[m] = Q
         stats = {"n_agg": 0, "n_stale": 0, "max_stale": 0,
                  "n_aborted": 0, "wasted_j": 0.0,
-                 "n_dispatches": 0, "lanes_dispatched": 0}
+                 "n_dispatches": 0, "lanes_dispatched": 0,
+                 "lanes_trained": 0}
+        chunk = dispatch_chunk(H)
 
         heap: list = []
         seq = 0
@@ -391,7 +428,6 @@ class AsyncHFLEngine:
                 return
             dmask = np.zeros(H, bool)
             dmask[slots] = True
-            # every dispatch trains all H lanes; len(slots) of them count
             with TraceAnnotation("async.dispatch", round=rnd):
                 if codec_on:
                     cohort_params, cohort_resid = \
@@ -409,6 +445,7 @@ class AsyncHFLEngine:
                         L=sp.L)
             stats["n_dispatches"] += 1
             stats["lanes_dispatched"] += len(slots)
+            stats["lanes_trained"] += -(-len(slots) // chunk) * chunk
             for s in slots:
                 start_ver[s] = edge_ver[assign_np[s]]
                 task_id[s] = next_task
@@ -539,6 +576,7 @@ class AsyncHFLEngine:
                "forced_flushes": forced,
                "n_dispatches": stats["n_dispatches"],
                "lanes_dispatched": stats["lanes_dispatched"],
+               "lanes_trained": stats["lanes_trained"],
                "msg_bits": cm.round_msg_bits(self.sp, stats["n_agg"], M,
                                              msg_bits=self.uplink_bits),
                "uplink_bytes": float(

@@ -8,16 +8,21 @@ event loop IS the synchronous round — allocations and per-task costs
 bitwise, T_i/E_i to float-accumulation-order tolerance, trained params
 and accuracy to ulp-level tolerance.
 """
+import functools
+
 import numpy as np
 import pytest
 
 jnp = pytest.importorskip("jax.numpy")
 import jax  # noqa: E402
 
+from repro.core import async_engine as ae  # noqa: E402
+from repro.core import compression as comp  # noqa: E402
 from repro.core import cost_model as cm  # noqa: E402
 from repro.core.async_engine import AsyncConfig, AsyncHFLEngine  # noqa: E402
 from repro.core.framework import round_step  # noqa: E402
 from repro.core.hfl import evaluate_in_batches  # noqa: E402
+from repro.core.local_train import cohort_local_sgd  # noqa: E402
 from repro.core.traffic import TrafficGenerator, TrafficParams  # noqa: E402
 from repro.data import make_dataset, partition_noniid  # noqa: E402
 
@@ -169,6 +174,140 @@ def test_degenerate_trace_matches_round_step_oracle():
         acc_sync = evaluate_in_batches(eng.apply_fn, params_sync,
                                        fed.X_test, fed.y_test)
         assert rec["acc"] == pytest.approx(acc_sync, abs=1e-6)
+
+
+# ------------------------------------------- chunked dispatch
+
+H_CHUNK = 12                       # a cohort of several chunks
+C = ae.DISPATCH_CHUNK
+# dispatch sizes around one chunk and the whole cohort, on lanes drawn
+# at random (so with gaps between them), and one more mask with gaps
+DISPATCHES = {
+    name: sorted(np.random.default_rng(k).choice(H_CHUNK, k, replace=False))
+    for name, k in (("1", 1), ("C-1", C - 1), ("C", C), ("C+1", C + 1),
+                    ("H", H_CHUNK))}
+DISPATCHES["gaps"] = [1, 4, 5, 9, 10]
+
+
+@functools.partial(jax.jit, static_argnames=("apply_fn", "L"))
+def _masked_oracle(apply_fn, cohort, edge, assign, dmask, X, y, mask, lr,
+                   *, L):
+    """The whole-cohort masked dispatch the chunked one replaced: every
+    lane trains, dispatched lanes from their edge model. Returns
+    ``(pulled, trained)`` for all H lanes."""
+    def bmask(leaf):
+        return dmask.reshape((-1,) + (1,) * (leaf.ndim - 1))
+
+    pulled = jax.tree.map(lambda e: jnp.take(e, assign, axis=0), edge)
+    src = jax.tree.map(lambda c, q: jnp.where(bmask(c), q, c), cohort,
+                       pulled)
+    return pulled, cohort_local_sgd(apply_fn, src, X, y, mask, L, lr)
+
+
+@pytest.fixture(scope="module")
+def chunk_world():
+    """An H=12 cohort whose lanes and edges all hold different models."""
+    sp, pop, fed = _world(seed=2)
+    eng = AsyncHFLEngine(sp, pop, fed, AsyncConfig(H=H, seed=2))
+    rows = np.arange(H_CHUNK) % N_DEV
+
+    def spread(n, seed):
+        keys = jax.random.split(jax.random.PRNGKey(seed), n)
+        return jax.tree.map(
+            lambda p: p[None] + 0.01 * jax.vmap(
+                lambda k: jax.random.normal(k, p.shape))(keys),
+            eng.model_params)
+
+    return dict(apply_fn=eng.apply_fn, L=sp.L, lr=0.01,
+                cohort=spread(H_CHUNK, 0), edge=spread(N_EDGE, 1),
+                assign=jnp.asarray(rows % N_EDGE, jnp.int32),
+                X=eng.X[rows], y=eng.y[rows], mask=eng.mask[rows])
+
+
+def _dispatch_mask(name):
+    dmask = np.zeros(H_CHUNK, bool)
+    dmask[DISPATCHES[name]] = True
+    return jnp.asarray(dmask)
+
+
+def _assert_rows(got, want, old, dmask):
+    """Dispatched rows close to ``want`` (unless it is None); the others
+    bitwise ``old``."""
+    on = np.asarray(dmask)
+    for g, o, w in zip(jax.tree.leaves(got), jax.tree.leaves(old),
+                       jax.tree.leaves(want) if want is not None
+                       else jax.tree.leaves(got)):
+        g, w, o = np.asarray(g), np.asarray(w), np.asarray(o)
+        np.testing.assert_allclose(g[on], w[on], rtol=1e-6)
+        np.testing.assert_array_equal(g[~on], o[~on])
+
+
+def test_a_chunk_is_never_wider_than_the_cohort():
+    assert 1 < ae.dispatch_chunk(H_CHUNK) == C < H_CHUNK
+    assert ae.dispatch_chunk(C - 1) == C - 1
+
+
+@pytest.mark.parametrize("name", list(DISPATCHES))
+def test_chunked_dispatch_matches_the_masked_oracle(chunk_world, name):
+    w, dmask = chunk_world, _dispatch_mask(name)
+    args = (w["apply_fn"], w["cohort"], w["edge"], w["assign"], dmask,
+            w["X"], w["y"], w["mask"], w["lr"])
+    got = ae._train_dispatched(*args, L=w["L"])
+    _, trained = _masked_oracle(*args, L=w["L"])
+    _assert_rows(got, trained, w["cohort"], dmask)
+
+
+@pytest.mark.parametrize("codec", ["bf16_delta", "topk"])
+def test_chunked_compressed_dispatch_matches_the_masked_oracle(
+        chunk_world, codec):
+    """Deterministic codecs: the reconstruction and the residual rows of
+    the dispatched lanes as the whole-cohort encode gives them."""
+    w = chunk_world
+    cc = comp.CompressionConfig(codec=codec)
+    resid = jax.tree.map(lambda c: 1e-3 * jnp.cos(c), w["cohort"])
+    key = jax.random.PRNGKey(4)
+    for name in DISPATCHES:
+        dmask = _dispatch_mask(name)
+        args = (w["apply_fn"], w["cohort"], w["edge"], w["assign"], dmask,
+                w["X"], w["y"], w["mask"], w["lr"])
+        got, got_resid = ae._train_dispatched_compressed(
+            *args, resid, key, L=w["L"], codec=cc)
+        pulled, trained = _masked_oracle(*args, L=w["L"])
+        delta = jax.tree.map(lambda t, q: t - q, trained, pulled)
+        dec, want_resid = comp.encode_decode(cc, key, delta, resid)
+        recon = jax.tree.map(jnp.add, pulled, dec)
+        _assert_rows(got, recon, w["cohort"], dmask)
+        _assert_rows(got_resid, want_resid, resid, dmask)
+
+
+def test_chunked_int8_dispatch_keeps_error_feedback_exact(chunk_world):
+    """Stochastic rounding draws per chunk, so the rows are checked
+    by what holds for any draw: reconstruction plus new residual is the
+    trained model plus the old residual, and each new residual lies
+    within one quantisation level of its row."""
+    w = chunk_world
+    cc = comp.CompressionConfig(codec="int8")
+    resid = jax.tree.map(lambda c: 1e-3 * jnp.cos(c), w["cohort"])
+    for name in DISPATCHES:
+        dmask = _dispatch_mask(name)
+        on = np.asarray(dmask)
+        args = (w["apply_fn"], w["cohort"], w["edge"], w["assign"], dmask,
+                w["X"], w["y"], w["mask"], w["lr"])
+        got, got_resid = ae._train_dispatched_compressed(
+            *args, resid, jax.random.PRNGKey(4), L=w["L"], codec=cc)
+        pulled, trained = _masked_oracle(*args, L=w["L"])
+        for g, r, t, q, o in zip(*(jax.tree.leaves(a) for a in (
+                got, got_resid, trained, pulled, resid))):
+            # a handful of f32 roundings of numbers the size of the params
+            np.testing.assert_allclose(
+                np.asarray(g + r)[on], np.asarray(t + o)[on], rtol=1e-6,
+                atol=8 * np.finfo(np.float32).eps * float(jnp.abs(q).max()))
+            x = np.asarray(t - q + o)[on].reshape(int(on.sum()), -1)
+            step = np.abs(x).max(axis=1, keepdims=True) / 127.0
+            nr = np.asarray(r)[on].reshape(x.shape)
+            assert (np.abs(nr) <= step * (1 + 1e-5)).all()
+        _assert_rows(got, None, w["cohort"], dmask)
+        _assert_rows(got_resid, None, resid, dmask)
 
 
 # -------------------------------------------------- async behaviour

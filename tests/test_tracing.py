@@ -5,7 +5,7 @@ The fused round program carries the named scopes ``allocate``,
 ``local_train`` and ``aggregate``; ``run_round`` and ``step_round``
 write host spans (``hfl.*``, ``async.*``, ``eval.*``) into a running
 ``jax.profiler`` trace; the round records count the cohort's
-padding share and the lanes each async dispatch trains.
+padding share and the lanes each async dispatch sends out and trains.
 """
 import dataclasses
 import glob
@@ -128,10 +128,15 @@ def test_dispatch_counters_match_the_dispatch_masks(world, monkeypatch,
     assert rec["n_dispatches"] == len(lanes) > 1
     assert rec["lanes_dispatched"] == sum(lanes)
     occupancy = rec["lanes_dispatched"] / (rec["n_dispatches"] * rec["H"])
-    # the first dispatch trains the whole cohort, every later one only
+    # the first dispatch sends out the whole cohort, every later one only
     # the members an edge flush sends back out
     assert lanes[0] == H
     assert 0.0 < occupancy < 1.0
+    # each dispatch trains its lanes in whole chunks of C
+    C = ae.dispatch_chunk(rec["H"])
+    assert rec["lanes_trained"] == sum(-(-k // C) * C for k in lanes)
+    assert (rec["lanes_dispatched"] <= rec["lanes_trained"]
+            < rec["lanes_dispatched"] + C * rec["n_dispatches"])
 
 
 # ------------------------------------------------------- host spans
