@@ -31,6 +31,7 @@ class SSMConfig:
     conv_width: int = 4
     chunk: int = 256
     n_groups: int = 1
+    conv_bias: bool = False          # bias on the causal conv of [x|B|C]
 
     def d_inner(self, d_model: int) -> int:
         return self.expand * d_model
@@ -56,6 +57,10 @@ class ModelConfig:
     hybrid_period: int = 0
     hybrid_attn_pos: int = 0
     rope_theta: float = 10_000.0
+    use_rope: bool = True            # False: no position embedding (NoPE)
+    attn_scale: float = 0.0          # softmax scale; 0 = 1/sqrt(head_dim)
+    embedding_multiplier: float = 1.0   # token embeddings times this
+    residual_multiplier: float = 1.0    # x + m * sublayer(x)
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     # "heads": Megatron col/row TP over attention heads (requires n_heads%tp==0)
@@ -134,6 +139,8 @@ class ModelConfig:
                 conv_dim = di + 2 * ssm.n_groups * ssm.d_state
                 total += D * (2 * di + 2 * ssm.n_groups * ssm.d_state + nh)
                 total += conv_dim * ssm.conv_width + 2 * nh + di  # conv + A/D + gate-norm
+                if ssm.conv_bias:
+                    total += conv_dim
                 total += di * D
             if self.mlp_kind(i) == "moe":
                 m = self.moe
@@ -152,6 +159,19 @@ class ModelConfig:
         n_moe_layers = sum(1 for i in range(self.n_layers) if self.mlp_kind(i) == "moe")
         unused = n_moe_layers * (m.num_experts - m.top_k) * 3 * self.d_model * self.d_ff
         return full - unused
+
+
+@dataclasses.dataclass(frozen=True)
+class LoRAConfig:
+    """Low-rank adapters (Hu et al., arXiv:2106.09685) on every dense
+    projection of a decoder: ``x W + (alpha / rank) (x A) B`` with the
+    base ``W`` frozen."""
+    rank: int = 16
+    alpha: float = 32.0
+
+    @property
+    def scale(self) -> float:
+        return self.alpha / self.rank
 
 
 @dataclasses.dataclass(frozen=True)

@@ -19,6 +19,7 @@ _MODULES = {
     "qwen3-moe-235b-a22b": "repro.configs.qwen3_moe_235b_a22b",
     "llama3-405b": "repro.configs.llama3_405b",
     "mistral-large-123b": "repro.configs.mistral_large_123b",
+    "granite-4.0-h-micro": "repro.configs.granite_4_0_h_micro",
     "hfl-cnn": "repro.configs.hfl_cnn",
 }
 
@@ -31,8 +32,7 @@ HFL_SMOKE_ARCHS: Tuple[str, ...] = (
     "hfl-cnn", "mistral-nemo-12b", "mamba2-2.7b", "qwen3-moe-235b-a22b")
 
 
-@functools.lru_cache(maxsize=None)
-def get_hfl_spec(arch: str):
+def get_hfl_spec(arch: str, scale: str = "smoke"):
     """Resolve ``--arch`` to the :class:`repro.models.spec.ModelSpec`
     the HFL engines train over.
 
@@ -41,17 +41,38 @@ def get_hfl_spec(arch: str):
     maps to its CPU-trainable ``smoke_config()`` variant (remat off,
     f32) wrapped as a sequence classifier over the synthetic
     ``make_seq_dataset`` task; the cost model prices whatever payload
-    comes back via ``model_bits``. Cached so repeated resolution returns
-    the SAME spec object — ``apply_fn`` is a static jit argument and
-    must not fragment the engines' jit caches.
+    comes back via ``model_bits``. An arch whose module defines ``LORA``
+    is LoRA fine-tuning of its frozen base (``spec.lora_spec``), and
+    ``scale="stage"`` gives it at the published widths with the layers
+    one pipeline stage holds (the module's ``stage_config()``, in its
+    own dtype, each layer recomputed in the backward pass and the lanes
+    trained ``STAGE_LANE_CHUNK`` at a time). Cached so repeated
+    resolution returns the SAME spec object — ``apply_fn`` is a static
+    jit argument and must not fragment the engines' jit caches.
     """
+    return _hfl_spec(arch, scale)
+
+
+@functools.lru_cache(maxsize=None)
+def _hfl_spec(arch: str, scale: str):
     from repro.models import spec as spec_lib
-    if arch == "hfl-cnn":
+    if arch == "hfl-cnn" and scale == "smoke":
         return spec_lib.cnn_spec()
     if arch not in _MODULES:
         raise KeyError(f"unknown arch {arch!r}; known: {sorted(_MODULES)}")
+    mod = importlib.import_module(_MODULES[arch])
+    lora = getattr(mod, "LORA", None)
+    if scale == "stage" and lora is not None:
+        return spec_lib.lora_spec(arch, mod.stage_config(), lora,
+                                  remat=True,
+                                  lane_chunk=mod.STAGE_LANE_CHUNK,
+                                  eval_batch=mod.STAGE_EVAL_BATCH)
+    if scale != "smoke":
+        raise ValueError(f"{arch!r} has no {scale!r} payload")
     cfg = dataclasses.replace(get_smoke_config(arch),
                               remat=False, dtype="float32")
+    if lora is not None:
+        return spec_lib.lora_spec(arch, cfg, lora)
     return spec_lib.seq_spec(arch, cfg)
 
 

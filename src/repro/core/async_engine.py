@@ -290,6 +290,10 @@ class AsyncHFLEngine:
         key = jax.random.PRNGKey(cfg.seed)
         k_model, _, _ = jax.random.split(key, 3)
         self.spec = get_hfl_spec(cfg.arch)
+        if self.spec.frozen_init_fn is not None:
+            raise ValueError(
+                f"AsyncHFLEngine trains whole payloads; {self.spec.arch!r} "
+                "fine-tunes a frozen base (use HFLFramework)")
         self.model_params = self.spec.init_fn(k_model, fed)
         self.apply_fn = self.spec.apply_fn
         self.sp = dataclasses.replace(

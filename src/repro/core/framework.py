@@ -32,7 +32,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Dict, List, Optional
+from typing import TYPE_CHECKING, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,12 +52,16 @@ from repro.configs.registry import get_hfl_spec
 from repro.data.partition import FederatedData
 from repro.utils import tree_bytes
 
+if TYPE_CHECKING:
+    from repro.models.spec import ModelSpec
+
 
 def round_step_core(apply_fn, sp: cm.SystemParams, params, u, D, p, g,
                     g_cloud, B_m, X, y, mask, sizes, assign, lr, *,
                     M: int, L: int, Q: int, alloc_steps: int,
                     agg_kernel: bool = False, codec=None,
-                    codec_state=None, codec_key=None):
+                    codec_state=None, codec_key=None, frozen=None,
+                    lane_chunk: int = 0):
     """Traceable fused round: one global iteration minus scheduling.
 
     Inputs are pre-gathered for the scheduled cohort: u/D/p/sizes (H,),
@@ -68,6 +72,9 @@ def round_step_core(apply_fn, sp: cm.SystemParams, params, u, D, p, g,
     the hierarchical aggregation (2)-(3) through the fused masked-weight
     ``kernels/hier_agg`` Pallas kernel (interpret off-TPU) instead of
     masked XLA einsums. Returns (new_params, (T_i, E_i, T_m, E_m, b, f)).
+    ``params`` is the trainable tree, ``frozen`` the payload's base (or
+    None), held once and read by local training alone, which trains
+    ``lane_chunk`` lanes at a time (``cohort_local_sgd``; 0: all).
 
     Compression: with an active ``codec`` (static
     ``CompressionConfig``), pass the caller's ``sp`` already patched
@@ -97,27 +104,30 @@ def round_step_core(apply_fn, sp: cm.SystemParams, params, u, D, p, g,
             apply_fn, params, X, y, mask, sizes, assign, M=M, L=L, Q=Q,
             lr=lr, agg_kernel=agg_kernel, codec=codec,
             dev_resid=dev_resid, edge_resid=edge_resid,
-            codec_key=codec_key)
+            codec_key=codec_key, frozen=frozen, lane_chunk=lane_chunk)
         return new_params, (dev_resid, edge_resid), (T_i, E_i, T_m, E_m,
                                                      b, f)
     new_params = hfl_global_iteration_core(
         apply_fn, params, X, y, mask, sizes, assign, M=M, L=L, Q=Q, lr=lr,
-        agg_kernel=agg_kernel)
+        agg_kernel=agg_kernel, frozen=frozen, lane_chunk=lane_chunk)
     return new_params, (T_i, E_i, T_m, E_m, b, f)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "apply_fn", "sp", "M", "L", "Q", "alloc_steps", "agg_kernel", "codec"))
+    "apply_fn", "sp", "M", "L", "Q", "alloc_steps", "agg_kernel", "codec",
+    "lane_chunk"))
 def round_step(apply_fn, sp: cm.SystemParams, params, u, D, p, g, g_cloud,
                B_m, X, y, mask, sizes, assign, lr, *, M: int, L: int,
                Q: int, alloc_steps: int, agg_kernel: bool = False,
-               codec=None, codec_state=None, codec_key=None):
+               codec=None, codec_state=None, codec_key=None, frozen=None,
+               lane_chunk: int = 0):
     """Jitted fused round — see ``round_step_core``."""
     return round_step_core(apply_fn, sp, params, u, D, p, g, g_cloud, B_m,
                            X, y, mask, sizes, assign, lr,
                            M=M, L=L, Q=Q, alloc_steps=alloc_steps,
                            agg_kernel=agg_kernel, codec=codec,
-                           codec_state=codec_state, codec_key=codec_key)
+                           codec_state=codec_state, codec_key=codec_key,
+                           frozen=frozen, lane_chunk=lane_chunk)
 
 
 @dataclasses.dataclass
@@ -144,19 +154,30 @@ class FrameworkConfig:
 class HFLFramework:
     def __init__(self, sp: cm.SystemParams, pop: cm.Population,
                  fed: FederatedData, cfg: FrameworkConfig,
-                 drl_params: Optional[dict] = None):
+                 drl_params: Optional[dict] = None,
+                 spec: Optional[ModelSpec] = None):
         self.sp, self.pop, self.fed, self.cfg = sp, pop, fed, cfg
         self.rng = np.random.default_rng(cfg.seed)
         key = jax.random.PRNGKey(cfg.seed)
         k_model, k_mini, k_cluster = jax.random.split(key, 3)
 
-        # payload resolution: cfg.arch -> ModelSpec. The default
-        # "hfl-cnn" reproduces the paper CNN construction bit for bit
-        # (same key-split order, same cnn_apply object -> same jit
-        # cache entries as the pre-spec engines).
-        self.spec = get_hfl_spec(cfg.arch)
+        # payload resolution: cfg.arch -> ModelSpec, unless the caller
+        # hands one in. The default "hfl-cnn" reproduces the paper CNN
+        # construction bit for bit (same key-split order, same cnn_apply
+        # object -> same jit cache entries as the pre-spec engines).
+        self.spec = spec if spec is not None else get_hfl_spec(cfg.arch)
         self.model_params = self.spec.init_fn(k_model, fed)
         self.apply_fn = self.spec.apply_fn
+        # a payload with a frozen base: held here once; model_params is
+        # then the trainable tree alone (what a device uploads)
+        self.frozen = None
+        if self.spec.frozen_init_fn is not None:
+            if cfg.scheduler == "vkc":
+                raise ValueError("vkc clusters with the whole model; a "
+                                 "payload with a frozen base needs ikc or "
+                                 "fedavg")
+            self.frozen = self.spec.frozen_init_fn(
+                jax.random.fold_in(k_model, 1), fed)
         self.model_bits = tree_bytes(self.model_params) * 8
         self.sp = dataclasses.replace(self.sp, model_bits=float(self.model_bits))
 
@@ -290,7 +311,8 @@ class HFLFramework:
                         agg_kernel=self.cfg.agg_kernel, codec=self.codec,
                         codec_state=(cohort_resid, edge_resid),
                         codec_key=comp.round_key(self.codec, self.cfg.seed,
-                                                 i))
+                                                 i), frozen=self.frozen,
+                        lane_chunk=self.spec.lane_chunk)
                     self.codec_state = (
                         jax.tree.map(lambda full, nr: full.at[sched].set(nr),
                                      dev_resid, cohort_resid),
@@ -301,11 +323,12 @@ class HFLFramework:
                         *cohort, self.cfg.lr,
                         M=pop.n_edges, L=sp.L, Q=sp.Q,
                         alloc_steps=self.cfg.alloc_steps,
-                        agg_kernel=self.cfg.agg_kernel)
+                        agg_kernel=self.cfg.agg_kernel, frozen=self.frozen,
+                        lane_chunk=self.spec.lane_chunk)
 
         with TraceAnnotation("hfl.eval", round=i):
-            acc = self.spec.eval_fn(self.model_params,
-                                    self.fed.X_test, self.fed.y_test)
+            acc = self.spec.eval_fn(self.model_params, self.fed.X_test,
+                                    self.fed.y_test, frozen=self.frozen)
         with TraceAnnotation("hfl.record", round=i):
             msg_bits = cm.round_msg_bits(self.sp, sp.Q * H, pop.n_edges,
                                          msg_bits=self.uplink_bits)
@@ -320,7 +343,16 @@ class HFLFramework:
                    # padding: trained on, masked out of the loss
                    "pad_share": 1.0 - int(self._n_valid[sched].sum())
                                  / (H * self.mask.shape[1]),
-                   "H": H}
+                   "H": H,
+                   # what each device sends (the trainable tree, coded)
+                   # and the base held once on the device
+                   "uplink_bits": float(self.uplink_bits),
+                   "frozen_bytes": float(tree_bytes(self.frozen))}
+            if jnp.issubdtype(self.X.dtype, jnp.integer):
+                # tokens the round's local training runs over (L Q steps)
+                steps = sp.L * sp.Q * int(np.prod(self.X.shape[2:]))
+                rec["tokens_valid"] = steps * int(self._n_valid[sched].sum())
+                rec["tokens_padded"] = steps * H * self.mask.shape[1]
         self.history.append(rec)
         return rec
 
@@ -350,7 +382,8 @@ class HFLFramework:
             self.apply_fn, self.model_params,
             self.X[sched], self.y[sched], self.mask[sched],
             self.pop.D[sched], jnp.asarray(assign),
-            M=pop.n_edges, L=sp.L, Q=sp.Q, lr=self.cfg.lr)
+            M=pop.n_edges, L=sp.L, Q=sp.Q, lr=self.cfg.lr, frozen=self.frozen,
+            lane_chunk=self.spec.lane_chunk)
         return T_i, E_i
 
     def run(self, verbose: bool = True) -> Dict:

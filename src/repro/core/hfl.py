@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.core.local_train import cohort_local_sgd
+from repro.core.local_train import apply_model, cohort_local_sgd
 from repro.data.partition import FederatedData
 
 def agg_matmul(a, b):
@@ -65,9 +65,16 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
                               sizes, assign, *, M: int, L: int, Q: int,
                               lr: float, agg_kernel: bool = False,
                               codec=None, dev_resid=None, edge_resid=None,
-                              codec_key=None):
+                              codec_key=None, frozen=None,
+                              lane_chunk: int = 0):
     """Algorithm 1, traceable core (no jit) — inlined by the fused round
     engine (``framework.round_step``) and vmapped by ``core.sweep``.
+
+    ``global_params`` is the trainable tree; ``frozen`` is the payload's
+    base (``ModelSpec.frozen_init_fn``), passed once to local training
+    and never broadcast to edges or devices, aggregated or encoded.
+    ``lane_chunk`` is local training's lanes per chunk
+    (``cohort_local_sgd``; 0: the whole cohort at once).
 
     X/y/mask: (H, Dmax, ...) for the scheduled cohort; sizes: (H,) D_n;
     assign: (H,) edge ids. ``agg_kernel=True`` routes eqs. (2)-(3)
@@ -143,7 +150,7 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
                 dev_params = jax.tree.map(
                     lambda e: jnp.take(e, assign, axis=0), edge_params)
             dev_params = cohort_local_sgd(apply_fn, dev_params, X, y, mask,
-                                          L, lr)
+                                          L, lr, frozen, lane_chunk)
             # (2): weighted average per edge; empty edges keep their model
             # (aggregate in f32, carry the model dtype through the scan)
             def agg(delta, old):
@@ -178,7 +185,8 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
         with jax.named_scope("local_train"):
             pulled = jax.tree.map(lambda e: jnp.take(e, assign, axis=0),
                                   edge_params)
-        trained = cohort_local_sgd(apply_fn, pulled, X, y, mask, L, lr)
+        trained = cohort_local_sgd(apply_fn, pulled, X, y, mask, L, lr,
+                                   frozen, lane_chunk)
         t_leaves, treedef = jax.tree.flatten(trained)
         p_leaves = jax.tree.leaves(pulled)
         r_leaves = jax.tree.leaves(resid)
@@ -216,19 +224,21 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
 
 
 @functools.partial(jax.jit, static_argnames=("apply_fn", "M", "L", "Q",
-                                             "agg_kernel", "codec"))
+                                             "agg_kernel", "codec",
+                                             "lane_chunk"))
 def hfl_global_iteration(apply_fn: Callable, global_params, X, y, mask,
                          sizes, assign, *, M: int, L: int, Q: int,
                          lr: float, agg_kernel: bool = False,
                          codec=None, dev_resid=None, edge_resid=None,
-                         codec_key=None):
+                         codec_key=None, frozen=None, lane_chunk: int = 0):
     """Jitted Algorithm 1 — see ``hfl_global_iteration_core``."""
     return hfl_global_iteration_core(apply_fn, global_params, X, y, mask,
                                      sizes, assign, M=M, L=L, Q=Q, lr=lr,
                                      agg_kernel=agg_kernel, codec=codec,
                                      dev_resid=dev_resid,
                                      edge_resid=edge_resid,
-                                     codec_key=codec_key)
+                                     codec_key=codec_key, frozen=frozen,
+                                     lane_chunk=lane_chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("apply_fn",))
@@ -238,14 +248,15 @@ def evaluate_accuracy(apply_fn: Callable, params, X_test, y_test):
 
 
 @functools.partial(jax.jit, static_argnames=("apply_fn",))
-def _count_correct(apply_fn: Callable, params, X, y, valid):
+def _count_correct(apply_fn: Callable, params, X, y, valid, frozen=None):
     """Correct predictions among rows where ``valid > 0`` (exact int)."""
-    logits = apply_fn(params, X)
+    logits = apply_model(apply_fn, params, X, frozen)
     hit = (jnp.argmax(logits, axis=-1) == y) & (valid > 0)
     return jnp.sum(hit.astype(jnp.int32))
 
 
-def evaluate_in_batches(apply_fn, params, X_test, y_test, batch: int = 512):
+def evaluate_in_batches(apply_fn, params, X_test, y_test, batch: int = 512,
+                        frozen=None):
     """Test accuracy in device-sized batches, host-accumulated.
 
     Chunks the test set so evaluation never materialises one
@@ -278,5 +289,5 @@ def evaluate_in_batches(apply_fn, params, X_test, y_test, batch: int = 512):
             chunk = jnp.asarray(Xc), jnp.asarray(yc), jnp.asarray(valid)
         # evaluation: the chunk's forward pass and the wait for its count
         with TraceAnnotation("eval.wait"):
-            correct += int(_count_correct(apply_fn, params, *chunk))
+            correct += int(_count_correct(apply_fn, params, *chunk, frozen))
     return correct / n

@@ -617,6 +617,10 @@ class SweepRunner:
         self.sp, self.lr, self.alloc_steps = sp, lr, alloc_steps
         self.arch = arch
         self.spec = get_hfl_spec(arch)
+        if self.spec.frozen_init_fn is not None:
+            raise ValueError(
+                f"SweepRunner trains whole payloads; {self.spec.arch!r} "
+                "fine-tunes a frozen base (use HFLFramework)")
         self.agg_kernel = agg_kernel
         self.lane_chunk = lane_chunk
         self.codec = (compression if compression is not None

@@ -1,6 +1,8 @@
 """Grouped-query attention with RoPE, full/sliding-window masks, KV cache.
 
 Supports:
+  * RoPE or no position embedding at all (``cfg.use_rope=False``, NoPE),
+    and an explicit softmax scale (``cfg.attn_scale``; 1/sqrt(hd) when 0),
   * train/prefill forward (causal or banded-causal for SWA),
   * single-token decode against a full or rolling (SWA) KV cache,
   * GQA with any n_kv_heads <= n_heads (kv replicated across groups).
@@ -17,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, dense_init, rope_freqs
+from repro.models.layers import apply_rope, dense_init, linear, rope_freqs
 from repro.parallel.sharder import NOOP, Sharder
 
 NEG_INF = -1e30
@@ -44,14 +46,21 @@ def _causal_mask(S: int, window: int, offset: int = 0) -> jnp.ndarray:
     return jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
 
 
-def _sdpa(q, k, v, mask) -> jnp.ndarray:
+def _scaled(scores, hd: int, scale: float):
+    """Scores times the softmax scale: ``scale``, or 1/sqrt(hd) if 0."""
+    if scale:
+        return scores * jnp.float32(scale)
+    return scores / jnp.sqrt(hd).astype(jnp.float32)
+
+
+def _sdpa(q, k, v, mask, scale: float = 0.0) -> jnp.ndarray:
     """q: (B,S,Hq,hd) k/v: (B,T,Hkv,hd); mask additive (S,T) or (B,1,1,S,T)."""
     B, S, Hq, hd = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, hd)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(hd).astype(jnp.float32)
+    scores = _scaled(scores, hd, scale)
     scores = scores + mask
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
@@ -64,7 +73,7 @@ CHUNK_Q = 2048
 
 def _chunked_sdpa(q, k, v, window: int, sharder: Sharder,
                   score_kind: str = "attn_scores_seq",
-                  unroll: bool = False) -> jnp.ndarray:
+                  unroll: bool = False, scale: float = 0.0) -> jnp.ndarray:
     """Query-chunked causal attention (flash-style, XLA level).
 
     Bounds the materialised score tile to (B, H, CHUNK_Q, S) — with the
@@ -92,7 +101,7 @@ def _chunked_sdpa(q, k, v, window: int, sharder: Sharder,
         G = Hq // kT.shape[2]
         qg = qc.reshape(B, bq, kT.shape[2], G, hd)
         scores = jnp.einsum("bskgd,btkd->bkgst", qg, kT).astype(jnp.float32)
-        scores = scores / jnp.sqrt(hd).astype(jnp.float32)
+        scores = _scaled(scores, hd, scale)
         scores = sharder.act(scores, score_kind)
         scores = scores + mask
         probs = jax.nn.softmax(scores, axis=-1).astype(vT.dtype)
@@ -109,18 +118,21 @@ def attn_forward(params, x, cfg: ModelConfig, *, pos_offset: int = 0,
     """Full-sequence causal attention (train / prefill)."""
     B, S, D = x.shape
     hd = cfg.hd
-    wq, wk, wv = (params[n].astype(x.dtype) for n in ("wq", "wk", "wv"))
-    q = (x @ wq).reshape(B, S, cfg.n_heads, hd)
-    k = (x @ wk).reshape(B, S, cfg.n_kv_heads, hd)
-    v = (x @ wv).reshape(B, S, cfg.n_kv_heads, hd)
+    q = linear(params["wq"], x).reshape(B, S, cfg.n_heads, hd)
+    k = linear(params["wk"], x).reshape(B, S, cfg.n_kv_heads, hd)
+    v = linear(params["wv"], x).reshape(B, S, cfg.n_kv_heads, hd)
     q = sharder.act(q, "act_heads")
     k = sharder.act(k, "act_kv_heads")
     v = sharder.act(v, "act_kv_heads")
-    pos = jnp.arange(S) + pos_offset
-    cos, sin = rope_freqs(hd, cfg.rope_theta, pos)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.use_rope:
+        pos = jnp.arange(S) + pos_offset
+        cos, sin = rope_freqs(hd, cfg.rope_theta, pos)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     if impl == "pallas":
+        if cfg.attn_scale:
+            raise NotImplementedError("the flash-attention kernel scales "
+                                      "by 1/sqrt(head_dim) only")
         from repro.kernels.flash_attention.ops import flash_attention
         out = flash_attention(q, k, v, causal=True,
                               window=cfg.sliding_window).reshape(B, S, -1)
@@ -135,7 +147,8 @@ def attn_forward(params, x, cfg: ModelConfig, *, pos_offset: int = 0,
         kind = ("attn_scores_heads" if cfg.tp_strategy == "heads"
                 else "attn_scores_seq")
         out = _chunked_sdpa(q, k, v, cfg.sliding_window, sharder,
-                            score_kind=kind, unroll=cfg.unroll_layers)
+                            score_kind=kind, unroll=cfg.unroll_layers,
+                            scale=cfg.attn_scale)
     else:
         # repeat kv to full q heads BEFORE the score einsum: with kv_heads
         # (2/4/8) < the 16-way model axis, the grouped (B,kv,G,S,T) score
@@ -149,8 +162,8 @@ def attn_forward(params, x, cfg: ModelConfig, *, pos_offset: int = 0,
             k = sharder.act(k, "act_heads")
             v = sharder.act(v, "act_heads")
         mask = _causal_mask(S, cfg.sliding_window, 0)
-        out = _sdpa(q, k, v, mask)
-    out = out @ params["wo"].astype(out.dtype)
+        out = _sdpa(q, k, v, mask, cfg.attn_scale)
+    out = linear(params["wo"], out)
     return sharder.act(out, "act_resid")
 
 
@@ -175,13 +188,13 @@ def attn_decode(params, x, cache, pos, cfg: ModelConfig, *,
     assert S1 == 1
     hd = cfg.hd
     slots = cache["k"].shape[1]
-    wq, wk, wv = (params[n].astype(x.dtype) for n in ("wq", "wk", "wv"))
-    q = (x @ wq).reshape(B, 1, cfg.n_heads, hd)
-    k = (x @ wk).reshape(B, 1, cfg.n_kv_heads, hd)
-    v = (x @ wv).reshape(B, 1, cfg.n_kv_heads, hd)
-    cos, sin = rope_freqs(hd, cfg.rope_theta, pos[None])
-    q = apply_rope(q, cos[None], sin[None])
-    k = apply_rope(k, cos[None], sin[None])
+    q = linear(params["wq"], x).reshape(B, 1, cfg.n_heads, hd)
+    k = linear(params["wk"], x).reshape(B, 1, cfg.n_kv_heads, hd)
+    v = linear(params["wv"], x).reshape(B, 1, cfg.n_kv_heads, hd)
+    if cfg.use_rope:
+        cos, sin = rope_freqs(hd, cfg.rope_theta, pos[None])
+        q = apply_rope(q, cos[None], sin[None])
+        k = apply_rope(k, cos[None], sin[None])
     slot = jnp.mod(pos, slots)
     new_k = jax.lax.dynamic_update_index_in_dim(cache["k"], k[:, 0].astype(cache["k"].dtype), slot, 1)
     new_v = jax.lax.dynamic_update_index_in_dim(cache["v"], v[:, 0].astype(cache["v"].dtype), slot, 1)
@@ -191,7 +204,7 @@ def attn_decode(params, x, cache, pos, cfg: ModelConfig, *,
     newest = pos - jnp.mod(pos - s_idx, slots)      # position held by slot s
     valid = newest >= jnp.maximum(0, pos - slots + 1)
     mask = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)[None, :]  # (1, slots)
-    out = _sdpa(q, new_k, new_v, mask)
-    out = out @ params["wo"].astype(out.dtype)
+    out = _sdpa(q, new_k, new_v, mask, cfg.attn_scale)
+    out = linear(params["wo"], out)
     out = sharder.act(out, "act_resid")
     return out, {"k": new_k, "v": new_v}

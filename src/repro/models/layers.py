@@ -1,6 +1,7 @@
 """Core layer primitives (pure-functional: params are plain pytrees)."""
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Optional
 
@@ -18,6 +19,28 @@ def he_normal(key, shape, fan_in=None, dtype=jnp.float32):
 
 def dense_init(key, d_in, d_out, dtype=jnp.float32):
     return he_normal(key, (d_in, d_out), fan_in=d_in, dtype=dtype)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class Adapted:
+    """A frozen matrix ``w`` (d_in, d_out) with a trainable low-rank
+    update ``a`` (d_in, r), ``b`` (r, d_out): ``linear`` computes
+    ``x w + scale (x a) b`` (LoRA, arXiv:2106.09685)."""
+    w: jax.Array
+    a: jax.Array
+    b: jax.Array
+    scale: float = dataclasses.field(metadata=dict(static=True))
+
+
+def linear(w, x):
+    """``x @ w`` with ``w`` cast to x's dtype; an ``Adapted`` ``w`` adds
+    its low-rank term."""
+    if isinstance(w, Adapted):
+        dt = x.dtype
+        return (x @ w.w.astype(dt)
+                + w.scale * ((x @ w.a.astype(dt)) @ w.b.astype(dt)))
+    return x @ w.astype(x.dtype)
 
 
 def embed_init(key, vocab, d_model, dtype=jnp.float32):
@@ -74,9 +97,9 @@ def mlp_init(key, d_model, d_ff, dtype=jnp.float32):
 
 
 def mlp_apply(params, x):
-    g = jax.nn.silu(x @ params["w_gate"])
-    u = x @ params["w_up"]
-    return (g * u) @ params["w_down"]
+    g = jax.nn.silu(linear(params["w_gate"], x))
+    u = linear(params["w_up"], x)
+    return linear(params["w_down"], g * u)
 
 
 # ---------------------------------------------------- depthwise causal conv

@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import causal_conv1d, dense_init, rmsnorm, rmsnorm_init
+from repro.models.layers import (causal_conv1d, dense_init, linear, rmsnorm,
+                                 rmsnorm_init)
 from repro.parallel.sharder import NOOP, Sharder
 
 
@@ -48,7 +49,11 @@ def mamba2_init(key, cfg: ModelConfig, dtype=jnp.float32):
     k1, k2, k3, k4, k5, k6, k7, k8, k9 = jax.random.split(key, 9)
     def conv(k, c):
         return (jax.random.normal(k, (c, s.conv_width)) * 0.1).astype(dtype)
-    return {
+    bias = {}
+    if s.conv_bias:
+        bias = {f"conv_{n}_bias": jnp.zeros((c,), dtype)
+                for n, c in (("x", di), ("b", gn), ("c", gn))}
+    return {**bias,
         "wz": dense_init(k1, D, di, dtype),
         "wx": dense_init(k2, D, di, dtype),
         "wb": dense_init(k3, D, gn, dtype),
@@ -67,12 +72,18 @@ def mamba2_init(key, cfg: ModelConfig, dtype=jnp.float32):
 
 def _project(params, hidden):
     """hidden @ {wz,wx,wb,wc,wdt} -> (z, x, B_, C_, dt)."""
-    dt_ = hidden.dtype
-    return (hidden @ params["wz"].astype(dt_),
-            hidden @ params["wx"].astype(dt_),
-            hidden @ params["wb"].astype(dt_),
-            hidden @ params["wc"].astype(dt_),
-            hidden @ params["wdt"].astype(dt_))
+    return tuple(linear(params[n], hidden)
+                 for n in ("wz", "wx", "wb", "wc", "wdt"))
+
+
+def _conv_act(params, name, u, state=None):
+    """Published Mamba-2 order: causal conv of ``u``, its bias if the
+    block has one, then SiLU. Returns (activation, new conv state)."""
+    y, state = causal_conv1d(u, params[f"conv_{name}"].astype(u.dtype),
+                             state=state)
+    if f"conv_{name}_bias" in params:
+        y = y + params[f"conv_{name}_bias"].astype(u.dtype)
+    return jax.nn.silu(y), state
 
 
 # ----------------------------------------------------------- SSD math
@@ -134,7 +145,10 @@ def ssd_chunked(x, dt, A, B_, C_, chunk: int, sharder: Sharder = NOOP):
     diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]    # (B,nc,i,j,H)
     li = jnp.arange(cs)
     causal = (li[:, None] >= li[None, :])[None, None, :, :, None]
-    L = jnp.where(causal, jnp.exp(diff), 0.0)
+    # mask before the exp: above the diagonal diff > 0 and exp(diff)
+    # overflows once a chunk decays past e^88, and inf times the zero
+    # gradient of the masked entries is NaN
+    L = jnp.exp(jnp.where(causal, diff, -jnp.inf))
     L = sharder.act(L, "ssm_chunk_ij")
     scores = jnp.einsum("bcihn,bcjhn->bcijh", Cr, Br) * L
     scores = sharder.act(scores, "ssm_chunk_ij")
@@ -199,9 +213,9 @@ def mamba2_forward(params, hidden, cfg: ModelConfig, *,
     di = s.d_inner(D)
     nh = s.n_heads(D)
     z, x, B_, C_, dt = _project(params, hidden)
-    x, _ = causal_conv1d(jax.nn.silu(x), params["conv_x"].astype(x.dtype))
-    B_, _ = causal_conv1d(jax.nn.silu(B_), params["conv_b"].astype(x.dtype))
-    C_, _ = causal_conv1d(jax.nn.silu(C_), params["conv_c"].astype(x.dtype))
+    x, _ = _conv_act(params, "x", x)
+    B_, _ = _conv_act(params, "b", B_)
+    C_, _ = _conv_act(params, "c", C_)
     x = x.reshape(B, S, nh, s.head_dim)
     x = sharder.act(x, "ssm_heads")
     B_ = B_.reshape(B, S, s.n_groups, s.d_state)
@@ -212,7 +226,7 @@ def mamba2_forward(params, hidden, cfg: ModelConfig, *,
     y = y + params["D_skip"][None, None, :, None] * x.astype(jnp.float32)
     y = y.reshape(B, S, di).astype(hidden.dtype)
     y = rmsnorm(params["gate_norm"], y * jax.nn.silu(z))
-    out = y @ params["out_proj"].astype(y.dtype)
+    out = linear(params["out_proj"], y)
     return sharder.act(out, "act_resid")
 
 
@@ -227,12 +241,9 @@ def mamba2_decode(params, hidden, cache, cfg: ModelConfig, *,
     z, x, B_, C_, dt = _project(params, hidden)
     # one shared rolling conv state over the [x|B|C] stream
     st_x, st_b, st_c = jnp.split(cache["conv"], [di, di + gn], axis=-1)
-    x, st_x = causal_conv1d(jax.nn.silu(x), params["conv_x"].astype(x.dtype),
-                            state=st_x)
-    B_, st_b = causal_conv1d(jax.nn.silu(B_), params["conv_b"].astype(x.dtype),
-                             state=st_b)
-    C_, st_c = causal_conv1d(jax.nn.silu(C_), params["conv_c"].astype(x.dtype),
-                             state=st_c)
+    x, st_x = _conv_act(params, "x", x, st_x)
+    B_, st_b = _conv_act(params, "b", B_, st_b)
+    C_, st_c = _conv_act(params, "c", C_, st_c)
     conv_state = jnp.concatenate([st_x, st_b, st_c], axis=-1)
     x = x[:, 0].reshape(B, nh, s.head_dim)
     B_ = B_.reshape(B, s.n_groups, s.d_state)
@@ -243,6 +254,6 @@ def mamba2_decode(params, hidden, cache, cfg: ModelConfig, *,
     y = y + params["D_skip"][None, :, None] * x.astype(jnp.float32)
     y = y.reshape(B, 1, di).astype(hidden.dtype)
     y = rmsnorm(params["gate_norm"], y * jax.nn.silu(z))
-    out = y @ params["out_proj"].astype(y.dtype)
+    out = linear(params["out_proj"], y)
     out = sharder.act(out, "act_resid")
     return out, {"ssm": state, "conv": conv_state}

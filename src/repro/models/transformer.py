@@ -97,7 +97,21 @@ def _embed_tokens(params, tokens, cfg: ModelConfig):
         x = jnp.take(emb, tokens + offs[None, None, :], axis=0).sum(axis=2)
     else:
         x = jnp.take(emb, tokens, axis=0)
-    return x.astype(cfg.compute_dtype)
+    return scale_embeddings(x.astype(cfg.compute_dtype), cfg)
+
+
+def scale_embeddings(x, cfg: ModelConfig):
+    """Token embeddings times ``cfg.embedding_multiplier`` (muP)."""
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def _residual(x, h, cfg: ModelConfig):
+    """``x + m h`` with m = ``cfg.residual_multiplier`` (muP)."""
+    if cfg.residual_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.residual_multiplier, h.dtype)
+    return x + h
 
 
 def _lm_head(params, x, cfg: ModelConfig):
@@ -112,28 +126,40 @@ def _lm_head(params, x, cfg: ModelConfig):
 # --------------------------------------------------------------- forward
 
 def _apply_layer(p, x, cfg: ModelConfig, idx: int, sharder: Sharder, impl: str):
+    """One layer: the token mixer and the MLP, each pre-normed and added
+    to the residual; device scopes ``attention`` / ``mamba`` and ``mlp``."""
     aux = jnp.zeros((), jnp.float32)
-    h = rmsnorm(p["norm1"], x, cfg.norm_eps)
     if cfg.layer_kind(idx) == "attn":
-        h = attn.attn_forward(p["mix"], h, cfg, sharder=sharder, impl=impl)
+        with jax.named_scope("attention"):
+            h = rmsnorm(p["norm1"], x, cfg.norm_eps)
+            h = attn.attn_forward(p["mix"], h, cfg, sharder=sharder,
+                                  impl=impl)
+            x = _residual(x, h, cfg)
     else:
-        h = m2.mamba2_forward(p["mix"], h, cfg, sharder=sharder)
-    x = x + h
+        with jax.named_scope("mamba"):
+            h = rmsnorm(p["norm1"], x, cfg.norm_eps)
+            h = m2.mamba2_forward(p["mix"], h, cfg, sharder=sharder)
+            x = _residual(x, h, cfg)
     kind = cfg.mlp_kind(idx)
     if kind != "none":
-        h = rmsnorm(p["norm2"], x, cfg.norm_eps)
-        if kind == "moe":
-            h, aux = moe_lib.moe_apply(p["mlp"], h, cfg, sharder=sharder)
-        else:
-            g = {k: v.astype(h.dtype) for k, v in p["mlp"].items()}
-            h = mlp_apply(g, h)
-        x = x + h
+        with jax.named_scope("mlp"):
+            h = rmsnorm(p["norm2"], x, cfg.norm_eps)
+            if kind == "moe":
+                h, aux = moe_lib.moe_apply(p["mlp"], h, cfg, sharder=sharder)
+            else:
+                h = mlp_apply(p["mlp"], h)
+            x = _residual(x, h, cfg)
     return sharder.act(x, "act_resid"), aux
 
 
 def backbone(params, x, cfg: ModelConfig, *, sharder: Sharder = NOOP,
-             impl: str = "xla") -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (B, S, D) embedded input -> (hidden, total_aux_loss)."""
+             impl: str = "xla", layer_remat: bool = False
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x: (B, S, D) embedded input -> (hidden, total_aux_loss).
+
+    ``layer_remat`` recomputes each layer in the backward pass (only
+    layer inputs are kept): for a super-block as deep as a whole model
+    (a hybrid period of 10), where ``cfg.remat`` keeps nothing back."""
     sb = super_block(cfg)
 
     def block_body(carry, block_params):
@@ -142,7 +168,11 @@ def backbone(params, x, cfg: ModelConfig, *, sharder: Sharder = NOOP,
             # [nested per-layer remat inside the super-block was tried for
             #  jamba's 73.5 GB/dev peak and REFUTED: +30% flops, +2 GB —
             #  the peak is not intra-block recompute; §Perf iteration 6]
-            h, a = _apply_layer(block_params[j], h, cfg, j, sharder, impl)
+            def layer(p, h, j=j):
+                return _apply_layer(p, h, cfg, j, sharder, impl)
+            if layer_remat:
+                layer = jax.checkpoint(layer)
+            h, a = layer(block_params[j], h)
             aux = aux + a
         return (h, aux), None
 
@@ -230,16 +260,15 @@ def decode(params, tokens, cache, pos, cfg: ModelConfig, *,
                 hn, nc = attn.attn_decode(p["mix"], hn, c, pos, cfg, sharder=sharder)
             else:
                 hn, nc = m2.mamba2_decode(p["mix"], hn, c, cfg, sharder=sharder)
-            h = h + hn
+            h = _residual(h, hn, cfg)
             kind = cfg.mlp_kind(j)
             if kind != "none":
                 hn = rmsnorm(p["norm2"], h, cfg.norm_eps)
                 if kind == "moe":
                     hn, _ = moe_lib.moe_apply(p["mlp"], hn, cfg, sharder=sharder)
                 else:
-                    g = {k: v.astype(hn.dtype) for k, v in p["mlp"].items()}
-                    hn = mlp_apply(g, hn)
-                h = h + hn
+                    hn = mlp_apply(p["mlp"], hn)
+                h = _residual(h, hn, cfg)
             new_caches.append(nc)
         return h, new_caches
 

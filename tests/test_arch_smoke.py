@@ -23,7 +23,8 @@ def _batch(cfg, batch=2, seq=16):
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_smoke_reduced_variant(arch):
     cfg = get_smoke_config(arch)
-    assert cfg.n_layers <= 4 and cfg.d_model <= 512
+    # a hybrid keeps one whole period of its layer pattern
+    assert cfg.n_layers <= max(4, cfg.hybrid_period) and cfg.d_model <= 512
     if cfg.is_moe:
         assert cfg.moe.num_experts <= 4
     params = T.init(KEY, cfg)
@@ -70,6 +71,9 @@ def test_full_config_metadata(arch):
                             n_kv_heads=8, d_ff=53248, vocab_size=128256),
         "mistral-large-123b": dict(n_layers=88, d_model=12288, n_heads=96,
                                    n_kv_heads=8, d_ff=28672, vocab_size=32768),
+        "granite-4.0-h-micro": dict(n_layers=40, d_model=2048, n_heads=32,
+                                    n_kv_heads=8, d_ff=8192,
+                                    vocab_size=100352),
     }[arch]
     for k, v in expected.items():
         assert getattr(cfg, k) == v, (arch, k, getattr(cfg, k), v)
