@@ -19,20 +19,46 @@ import jax.numpy as jnp
 from repro.models.layers import he_normal
 
 
-def _conv(x, w):
-    """VALID 2D conv via im2col + GEMM.
-
-    On XLA:CPU the direct lax.conv path (and especially the
-    SelectAndScatter backward of reduce_window pooling) is ~10x slower
-    than a patches-matmul formulation; the HFL trainer calls this inside
-    a vmapped Q*L-deep scan, so it is the simulation's hot loop.
-    """
+def _conv_im2col(x, w):
+    """VALID 2D conv, stride 1, as im2col + GEMM: the k*k shifted slices
+    are stacked into a (B, oh, ow, k*k*C) patch tensor and multiplied by
+    the flattened kernel."""
     kh, kw, ci, co = w.shape
     B, H, W, C = x.shape
     oh, ow = H - kh + 1, W - kw + 1
     patches = jnp.stack([x[:, i:i + oh, j:j + ow, :]
                          for i in range(kh) for j in range(kw)], axis=3)
     return patches.reshape(B, oh, ow, kh * kw * C) @ w.reshape(kh * kw * ci, co)
+
+
+def _conv_native(x, w):
+    """VALID 2D conv, stride 1, as the compiler's own convolution."""
+    return jax.lax.conv_general_dilated(x, w, (1, 1), "VALID",
+                                        dimension_numbers=("NHWC", "HWIO",
+                                                           "NHWC"))
+
+
+def _conv(x, w):
+    """VALID 2D conv, lowered per platform when the program compiles.
+
+    On XLA:CPU the native conv (and its backward) is 6-10x slower than
+    im2col + GEMM, so the CPU keeps :func:`_conv_im2col`. Everywhere
+    else :func:`_conv_native`: on the TPU the im2col patch tensors have
+    minor dimensions of 75 or 375 against 128-wide tiles, and writing
+    and reading them back in f32 made local training (a vmapped
+    Q*L-deep scan of this conv, forward and backward) memory-bound.
+
+    A kernel with one input channel keeps im2col on every platform:
+    vmapped over lanes, the TPU's native conv folds the lanes into one
+    dense conv with lanes x out-channels features and lays its output
+    out again twice, which on a v5e took a paper-width FMNIST local
+    step from 40 to 64 ms. Both lowerings contract at the default
+    matmul precision and accumulate in f32.
+    """
+    if w.shape[2] == 1:
+        return _conv_im2col(x, w)
+    return jax.lax.platform_dependent(x, w, cpu=_conv_im2col,
+                                      default=_conv_native)
 
 
 def _maxpool2(x):

@@ -5,15 +5,18 @@ alignment, fast-memory limits, programs too large for the chip). These
 tests compile the main path's Pallas kernels — and the kernel-routed
 fused round — for one chip of a described ``v5e:2x2`` topology with
 ``interpret=False`` and check that a Mosaic kernel (``tpu_custom_call``)
-is in the compiled program. Nothing runs: no result or time comes from
-here. Widths are the paper's: M=5 edges, H=50 scheduled devices, the
-114,383-parameter CNN, N=100 devices for K-means, D_n up to 700.
+is in the compiled program, and that local training's convolutions
+lower to the compiler's own convolution. Nothing runs: no result or
+time comes from here. Widths are the paper's: M=5 edges, H=50 scheduled
+devices, the 114,383-parameter CNN, N=100 devices for K-means, D_n up
+to 700.
 
 The topology is described inside a module fixture (never at import),
 so every pytest-xdist worker collects the same tests and only the one
 given this file loads the TPU compiler.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -133,3 +136,40 @@ def test_kernel_round_step_compiles_for_v5e(one_chip, no_compile_cache,
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < 16e9
+
+
+def test_local_train_convs_lower_natively_for_v5e(one_chip, no_compile_cache):
+    """On the TPU ``models.cnn._conv`` lowers conv2 (15 input channels)
+    to the compiler's own convolution and keeps im2col for conv1 (one
+    input channel): one local step of the paper-width cohort (L=1)
+    holds exactly conv2's ``conv_general_dilated`` and its two
+    gradients, no f32 im2col patch tensor, and moves well under the
+    85.85e9 bytes that im2col for both convs compiles to (32.3e9)."""
+    from repro.core.local_train import cohort_local_sgd
+    from repro.models.cnn import cnn_apply, cnn_init
+
+    f32 = jnp.float32
+    params = jax.eval_shape(
+        lambda k: cnn_init(k, (28, 28), 1), jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda x: _spec((H,) + x.shape, x.dtype, one_chip), params)
+    step = jax.jit(lambda p, X, y, m: cohort_local_sgd(
+        cnn_apply, p, X, y, m, L=1, lr=0.01))
+    lowered = step.lower(
+        params, _spec((H, D_MAX, 28, 28, 1), f32, one_chip),
+        _spec((H, D_MAX), jnp.int32, one_chip),
+        _spec((H, D_MAX), f32, one_chip))
+    module = lowered.as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', module, re.M))
+    convs = [re.search(r"loc\((#loc\d+)\)$", line.strip()).group(1)
+             for line in module.splitlines()
+             if "stablehlo.convolution" in line]
+    assert len(convs) == 3, convs
+    assert all(locs[c].endswith("conv_general_dilated") for c in convs)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert f"f32[{H},{D_MAX},8,8,375]" not in text
+    assert f"f32[{H},{D_MAX},24,24,25]" not in text
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 50e9
