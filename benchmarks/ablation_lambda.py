@@ -88,7 +88,7 @@ def noniid_severity(fracs=(0.3, 0.8), iters=5, H=20):
             for i in range(iters):
                 sel = np.asarray(sched.schedule(rng))
                 assign = np.asarray(sel % sp.n_edges)
-                params = hfl_global_iteration(
+                params, _ = hfl_global_iteration(
                     cnn.cnn_apply, params, Xp[sel], yp[sel], mask[sel],
                     np.asarray(fed.sizes[sel], np.float32), assign,
                     M=sp.n_edges, L=sp.L, Q=sp.Q, lr=0.03)

@@ -106,8 +106,8 @@ def _sync_round_ms(sp, pop, fed, H, repeat=3, seed=0):
     spp = probe.sp
 
     def one(params):
-        out, _ = round_step(
-            probe.apply_fn, spp, params,
+        (out, _), _ = round_step(
+            probe.apply_fn, spp, (params, (None, None)),
             pop.u[sched], pop.D[sched], pop.p[sched], pop.g[sched],
             pop.g_cloud, pop.B_m,
             probe.X[sched], probe.y[sched], probe.mask[sched],

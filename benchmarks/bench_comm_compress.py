@@ -78,17 +78,18 @@ def _encode_decode_ms(codec_cfg, params, H, repeat=5):
     delta = jax.tree.map(
         lambda p: jnp.broadcast_to(p[None] * 1e-3, (H,) + p.shape)
         .astype(jnp.float32), params)
-    resid = jax.tree.map(jnp.zeros_like, delta)
+    zero = jax.tree.map(jnp.zeros_like, delta)     # ref and residual
     key = jax.random.PRNGKey(0)
 
     @jax.jit
     def roundtrip(d, r):
-        return comp.encode_decode(codec_cfg, key, d, r)
+        msg, r = comp.uplink(codec_cfg, key, zero, d, r)
+        return comp.receive(codec_cfg, msg, zero), r
 
-    out = jax.block_until_ready(roundtrip(delta, resid))    # compile
+    out = jax.block_until_ready(roundtrip(delta, zero))     # compile
     t0 = time.perf_counter()
     for _ in range(repeat):
-        out = jax.block_until_ready(roundtrip(delta, resid))
+        out = jax.block_until_ready(roundtrip(delta, zero))
     del out
     return (time.perf_counter() - t0) / repeat * 1e3
 

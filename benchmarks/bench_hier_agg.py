@@ -128,8 +128,8 @@ def _e2e_round(M, H, feats, alloc_steps, repeat):
     sp, pop, sched, assign, X, y, mask, w0 = _round_world(M, H, feats)
 
     def one(agg_kernel):
-        w, (T_i, E_i, _, _, _, _) = round_step(
-            _linear_apply, sp, w0, pop.u[sched], pop.D[sched],
+        (w, _), (T_i, E_i, _, _, _, _) = round_step(
+            _linear_apply, sp, (w0, (None, None)), pop.u[sched], pop.D[sched],
             pop.p[sched], pop.g[sched], pop.g_cloud, pop.B_m, X, y, mask,
             pop.D[sched], jnp.asarray(assign), 0.05, M=M, L=sp.L, Q=sp.Q,
             alloc_steps=alloc_steps, agg_kernel=agg_kernel)

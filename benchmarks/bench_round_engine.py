@@ -80,20 +80,20 @@ def sequential_round(sp, pop, sched, assign, X, y, mask, w0,
     T_i, E_i, _, _ = cm.round_cost(sp, pop, jnp.asarray(sched),
                                    jnp.asarray(assign), jnp.asarray(b),
                                    jnp.asarray(f))
-    w = hfl_global_iteration(_linear_apply, w0, X, y, mask, pop.D[sched],
-                             jnp.asarray(assign), M=pop.n_edges, L=sp.L,
-                             Q=sp.Q, lr=0.05)
+    w, _ = hfl_global_iteration(_linear_apply, w0, X, y, mask, pop.D[sched],
+                                jnp.asarray(assign), M=pop.n_edges, L=sp.L,
+                                Q=sp.Q, lr=0.05)
     jax.block_until_ready((w, T_i, E_i))
     return float(T_i), float(E_i)
 
 
 def fused_round(sp, pop, sched, assign, X, y, mask, w0,
                 alloc_steps: int = ALLOC_STEPS):
-    w, (T_i, E_i, _, _, _, _) = round_step(
-        _linear_apply, sp, w0, pop.u[sched], pop.D[sched], pop.p[sched],
-        pop.g[sched], pop.g_cloud, pop.B_m, X, y, mask, pop.D[sched],
-        jnp.asarray(assign), 0.05, M=pop.n_edges, L=sp.L, Q=sp.Q,
-        alloc_steps=alloc_steps)
+    (w, _), (T_i, E_i, _, _, _, _) = round_step(
+        _linear_apply, sp, (w0, (None, None)), pop.u[sched], pop.D[sched],
+        pop.p[sched], pop.g[sched], pop.g_cloud, pop.B_m, X, y, mask,
+        pop.D[sched], jnp.asarray(assign), 0.05, M=pop.n_edges, L=sp.L,
+        Q=sp.Q, alloc_steps=alloc_steps)
     jax.block_until_ready((w, T_i, E_i))
     return float(T_i), float(E_i)
 

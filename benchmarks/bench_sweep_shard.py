@@ -116,7 +116,7 @@ def _measure(lanes, n_emu, *, n_devices, m_edges, h_cohort, alloc_steps,
                 fn, kw["mesh"] = sweep_round_sharded, runner.mesh
 
             def call():
-                _, (T, _) = fn(runner.apply_fn, spp, runner.params0,
+                _, (T, _), _ = fn(runner.apply_fn, spp, runner.params0,
                                runner.u_b, runner.D_b, runner.p_b,
                                runner.g_b, runner.g_cloud_b, runner.B_m_b,
                                runner.X_b, runner.y_b, runner.mask_b,

@@ -438,6 +438,7 @@ def phase_serve(st, ph, state):
     import jax.numpy as jnp
 
     from repro.core.async_engine import _train_dispatched
+    from repro.core.compression import CompressionConfig
     from repro.launch.serve import run_serve
     from repro.models.cnn import cnn_init
     from repro.models.spec import cnn_spec
@@ -457,12 +458,13 @@ def phase_serve(st, ph, state):
                 tree)
         t0 = time.perf_counter()
         lowered = _train_dispatched.lower(
-            cnn_spec().apply_fn, rows(H), rows(M),
+            cnn_spec().apply_fn, (rows(H), None), rows(M),
             jax.ShapeDtypeStruct((H,), jnp.int32),
             jax.ShapeDtypeStruct((H,), jnp.bool_),
             jax.ShapeDtypeStruct((H, Dmax, 28, 28, 1), f32),
             jax.ShapeDtypeStruct((H, Dmax), jnp.int32),
-            jax.ShapeDtypeStruct((H, Dmax), f32), 0.01, L=st.L)
+            jax.ShapeDtypeStruct((H, Dmax), f32), 0.01, None,
+            L=st.L, codec=CompressionConfig())
         fits, need = _compile_fits(lowered, jax.devices()[0], False)
         sizes[H] = {"fits": fits, "bytes": need,
                     "compile_s": time.perf_counter() - t0}

@@ -53,7 +53,8 @@ from repro.core import compression as comp
 from repro.core import cost_model as cm
 from repro.core import resource as ra
 from repro.configs.registry import get_hfl_spec
-from repro.core.hfl import agg_matmul, evaluate_in_batches, pad_device_data
+from repro.core.hfl import (agg_matmul, einsum_panel, evaluate_in_batches,
+                            pad_device_data)
 from repro.core.local_train import cohort_local_sgd
 from repro.data.partition import FederatedData
 from repro.utils import tree_bytes
@@ -98,19 +99,26 @@ def dispatch_chunk(H: int) -> int:
     return min(DISPATCH_CHUNK, H)
 
 
-def _train_in_chunks(apply_fn, edge_params, assign, dispatch_mask, X, y,
-                     mask, lr, L, carry, keep):
-    """Train the dispatched lanes, C at a time, into ``(H, ...)`` rows.
+@functools.partial(jax.jit, static_argnames=("apply_fn", "L", "codec"))
+def _train_dispatched(apply_fn, cohort, edge_params, assign, dispatch_mask,
+                      X, y, mask, lr, key, *, L: int,
+                      codec: comp.CompressionConfig):
+    """Pull edge models and run L local GD steps on the dispatched lanes;
+    buffer what each edge receives of their uplinks.
 
-    The dispatched lanes are compacted in lane order and padded with the
-    out-of-range index H to whole chunks; ``ceil(k / C)`` chunks run in
-    one ``fori_loop``, so one compiled program serves every dispatch
-    pattern. Chunk ``i`` pulls its lanes' edge models, trains them with
-    ``cohort_local_sgd`` and scatters ``keep(i, lanes, carry, pulled,
-    trained)`` (a pytree shaped like ``carry`` with C rows) back into
-    ``carry``. Padding lanes train a copy of the chunk's first lane and
-    are dropped by the scatter; lanes not dispatched come out bitwise
-    unchanged.
+    Only the lanes where ``dispatch_mask`` is set train, from their
+    edge's current model, C at a time: the dispatched lanes are
+    compacted in lane order and padded with the out-of-range index H to
+    whole chunks, and ``ceil(k / C)`` chunks run in one ``fori_loop``,
+    so one compiled program serves every dispatch pattern. Padding lanes
+    train a copy of the chunk's first lane and are dropped by the
+    scatter. A trained lane's row becomes ``compression.receive`` of its
+    ``compression.uplink`` against the pulled model (the trained model
+    itself for the identity codec); chunk ``i`` encodes with
+    ``fold_in(key, i)``. ``cohort``: the ``(cohort_params, resid)``
+    pair, ``resid`` the cohort's (H, ...) error-feedback rows (None for
+    the identity codec). Rows not dispatched come out bitwise unchanged.
+    Returns the updated pair.
     """
     H = dispatch_mask.shape[0]
     C = dispatch_chunk(H)
@@ -124,54 +132,14 @@ def _train_in_chunks(apply_fn, edge_params, assign, dispatch_mask, X, y,
                               edge_params)
         trained = cohort_local_sgd(apply_fn, pulled, X[lanes], y[lanes],
                                    mask[lanes], L, lr)
-        rows = keep(i, lanes, carry, pulled, trained)
+        msg, rows_resid = comp.uplink(
+            codec, comp.fold_in(key, i), pulled, trained,
+            jax.tree.map(lambda r: r[lanes], carry[1]))
+        rows = (comp.receive(codec, msg, pulled), rows_resid)
         return jax.tree.map(lambda c, r: c.at[part].set(r, mode="drop"),
                             carry, rows)
 
-    return jax.lax.fori_loop(0, n, chunk, carry)
-
-
-@functools.partial(jax.jit, static_argnames=("apply_fn", "L"))
-def _train_dispatched(apply_fn, cohort_params, edge_params, assign,
-                      dispatch_mask, X, y, mask, lr, *, L: int):
-    """Pull edge models and run L local GD steps on the dispatched lanes.
-
-    Only the lanes where ``dispatch_mask`` is set train, from their
-    edge's current model, in fixed chunks (``_train_in_chunks``); the
-    other lanes of ``cohort_params`` are returned as they came.
-    """
-    return _train_in_chunks(
-        apply_fn, edge_params, assign, dispatch_mask, X, y, mask, lr, L,
-        cohort_params, lambda i, lanes, carry, pulled, trained: trained)
-
-
-@functools.partial(jax.jit, static_argnames=("apply_fn", "L", "codec"))
-def _train_dispatched_compressed(apply_fn, cohort_params, edge_params,
-                                 assign, dispatch_mask, X, y, mask, lr,
-                                 resid, key, *, L: int, codec):
-    """``_train_dispatched`` with the uplink codec applied.
-
-    Dispatched lanes train from their edge model, then ship
-    ``encode(trained - pulled + resid)``; the buffered value is the
-    edge-side reconstruction ``pulled + decode(...)`` (the staleness-
-    weighted flush is linear in the decoded update, so merging the
-    reconstruction is exactly merging the wire-format update).
-    ``resid``: (H, ...) error-feedback rows for the scheduled cohort —
-    updated only on dispatched lanes, like the params. Chunk ``i``
-    encodes with ``fold_in(key, i)``.
-    """
-    def keep(i, lanes, carry, pulled, trained):
-        delta = jax.tree.map(lambda t, q: (t - q).astype(jnp.float32),
-                             trained, pulled)
-        dec, new_resid = comp.encode_decode(
-            codec, jax.random.fold_in(key, i), delta,
-            jax.tree.map(lambda r: r[lanes], carry[1]))
-        recon = jax.tree.map(lambda q, d: (q + d).astype(q.dtype),
-                             pulled, dec)
-        return recon, new_resid
-
-    return _train_in_chunks(apply_fn, edge_params, assign, dispatch_mask, X,
-                            y, mask, lr, L, (cohort_params, resid), keep)
+    return jax.lax.fori_loop(0, n, chunk, cohort)
 
 
 @jax.jit
@@ -205,46 +173,22 @@ def _flush_edge(edge_params, cohort_params, m, deliver_mask, member_mask,
     return jax.tree.map(agg, edge_params, cohort_params)
 
 
-@functools.partial(jax.jit, static_argnames=("M",))
-def _cloud_agg(edge_params, assign, sizes, *, M: int):
-    """Eq. (3): cloud aggregation with cohort-data-size weights —
-    identical op order to ``hfl_global_iteration_core``'s cloud path."""
+@functools.partial(jax.jit, static_argnames=("M", "codec"))
+def _cloud_agg(edge_params, assign, sizes, *, global_params, resid, key,
+               M: int, codec: comp.CompressionConfig):
+    """Eq. (3): cloud aggregation with cohort-data-size weights, each
+    edge's upload through ``codec`` against ``global_params`` — the op
+    order of ``hfl_global_iteration_core``'s cloud hop. ``resid``: the
+    (M, ...) edge error-feedback rows. Returns ``(new_global, resid)``."""
     onehot = jax.nn.one_hot(assign, M, dtype=jnp.float32)
     w_dev = sizes.astype(jnp.float32)
     edge_tot = agg_matmul(onehot.T, w_dev)
     w = jnp.where(edge_tot > 0, edge_tot, 0.0)
     w = w / jnp.maximum(jnp.sum(w), 1.0)
-
-    def agg(e):
-        flat = e.reshape(M, -1)
-        return agg_matmul(w, flat).reshape(e.shape[1:]).astype(e.dtype)
-
-    return jax.tree.map(agg, edge_params)
-
-
-@functools.partial(jax.jit, static_argnames=("M", "codec"))
-def _cloud_agg_compressed(edge_params, global_params, assign, sizes, resid,
-                          key, *, M: int, codec):
-    """Compressed eq.-(3): each edge ships ``encode(edge - global)``, the
-    cloud aggregates the decoded deltas in delta space (identical weights
-    to ``_cloud_agg`` — exact when the codec is lossless). Returns
-    ``(new_global, new_edge_resid)``."""
-    onehot = jax.nn.one_hot(assign, M, dtype=jnp.float32)
-    edge_tot = agg_matmul(onehot.T, sizes.astype(jnp.float32))
-    w = jnp.where(edge_tot > 0, edge_tot, 0.0)
-    w = w / jnp.maximum(jnp.sum(w), 1.0)
-
-    delta = jax.tree.map(
-        lambda e, g_: (e - g_[None]).astype(jnp.float32),
-        edge_params, global_params)
-    dec, new_resid = comp.encode_decode(codec, key, delta, resid)
-
-    def agg(g_, d):
-        flat = d.reshape(M, -1)
-        return (g_.reshape(-1) + agg_matmul(w, flat)).reshape(
-            g_.shape).astype(g_.dtype)
-
-    return jax.tree.map(agg, global_params, dec), new_resid
+    msg, resid = comp.uplink(codec, key, global_params, edge_params, resid)
+    new = comp.aggregate(codec, einsum_panel(codec, w), msg, global_params)
+    return jax.tree.map(lambda n, g: n.astype(g.dtype), new,
+                        global_params), resid
 
 
 # ----------------------------------------------------------- the engine
@@ -371,21 +315,19 @@ class AsyncHFLEngine:
             lat = (np.asarray(tc, np.float64)
                    * self.trace.latency_scale[sched])
 
-        codec_on = self.codec.active
-        cohort_resid = None
-        if codec_on:
-            cohort_resid = jax.tree.map(lambda r_: r_[sched],
-                                        self.dev_resid)
-            k_disp, k_cloud = jax.random.split(
-                comp.round_key(self.codec, cfg.seed, self.round))
+        keys = comp.split(comp.round_key(self.codec, cfg.seed, self.round),
+                          2)
+        k_disp, k_cloud = comp.key_at(keys, 0), comp.key_at(keys, 1)
 
         Xc, yc, mc = self.X[sched], self.y[sched], self.mask[sched]
         edge_params = jax.tree.map(
             lambda g_: jnp.broadcast_to(g_[None], (M,) + g_.shape),
             self.model_params)
-        cohort_params = jax.tree.map(
+        # the cohort's lanes and their error-feedback rows
+        cohort = (jax.tree.map(
             lambda g_: jnp.broadcast_to(g_[None], (H,) + g_.shape),
-            self.model_params)
+            self.model_params),
+            jax.tree.map(lambda r_: r_[sched], self.dev_resid))
 
         # --- per-slot event-loop state (cohort-indexed)
         up = self.trace.up_at(t0)[sched].copy()      # (H,) availability
@@ -424,7 +366,7 @@ class AsyncHFLEngine:
                 push(float(tog_rows[s][i]), "toggle", s)
 
         def dispatch(slots, t):
-            nonlocal cohort_params, cohort_resid, next_task
+            nonlocal cohort, next_task
             slots = [s for s in slots
                      if up[s] and not delivered[s] and task_id[s] < 0
                      and flushes[assign_np[s]] < Q]
@@ -433,20 +375,11 @@ class AsyncHFLEngine:
             dmask = np.zeros(H, bool)
             dmask[slots] = True
             with TraceAnnotation("async.dispatch", round=rnd):
-                if codec_on:
-                    cohort_params, cohort_resid = \
-                        _train_dispatched_compressed(
-                            self.apply_fn, cohort_params, edge_params,
-                            assign_j, jnp.asarray(dmask), Xc, yc, mc,
-                            cfg.lr, cohort_resid,
-                            jax.random.fold_in(k_disp,
-                                               stats["n_dispatches"]),
-                            L=sp.L, codec=self.codec)
-                else:
-                    cohort_params = _train_dispatched(
-                        self.apply_fn, cohort_params, edge_params,
-                        assign_j, jnp.asarray(dmask), Xc, yc, mc, cfg.lr,
-                        L=sp.L)
+                cohort = _train_dispatched(
+                    self.apply_fn, cohort, edge_params, assign_j,
+                    jnp.asarray(dmask), Xc, yc, mc, cfg.lr,
+                    comp.fold_in(k_disp, stats["n_dispatches"]), L=sp.L,
+                    codec=self.codec)
             stats["n_dispatches"] += 1
             stats["lanes_dispatched"] += len(slots)
             stats["lanes_trained"] += -(-len(slots) // chunk) * chunk
@@ -470,7 +403,7 @@ class AsyncHFLEngine:
             stal = np.where(del_mask, edge_ver[m] - start_ver, 0)
             with TraceAnnotation("async.flush", round=rnd):
                 edge_params = _flush_edge(
-                    edge_params, cohort_params, jnp.int32(m),
+                    edge_params, cohort[0], jnp.int32(m),
                     jnp.asarray(del_mask), jnp.asarray(mem_mask),
                     sizes, jnp.asarray(stal, jnp.float32),
                     jnp.float32(cfg.staleness_exp))
@@ -551,16 +484,12 @@ class AsyncHFLEngine:
         T_round = float(T_m.max()) if M else 0.0
         E_round = float(edge_energy.sum() + np.asarray(E_cl).sum())
         with TraceAnnotation("async.cloud_agg", round=rnd):
-            if codec_on:
-                self.model_params, self.edge_resid = _cloud_agg_compressed(
-                    edge_params, self.model_params, assign_j, sizes,
-                    self.edge_resid, k_cloud, M=M, codec=self.codec)
-                self.dev_resid = jax.tree.map(
-                    lambda full, r_: full.at[jnp.asarray(sched)].set(r_),
-                    self.dev_resid, cohort_resid)
-            else:
-                self.model_params = _cloud_agg(edge_params, assign_j, sizes,
-                                               M=M)
+            self.model_params, self.edge_resid = _cloud_agg(
+                edge_params, assign_j, sizes, global_params=self.model_params,
+                resid=self.edge_resid, key=k_cloud, M=M, codec=self.codec)
+            self.dev_resid = jax.tree.map(
+                lambda full, r_: full.at[jnp.asarray(sched)].set(r_),
+                self.dev_resid, cohort[1])
         self.t = t0 + T_round
         self.round += 1
 

@@ -1,15 +1,13 @@
 """Communication compression for uplink model updates — codecs + error
 feedback, charged end-to-end in the cost model.
 
-Every engine up to PR 8 ships full-precision ``model_bits`` payloads on
-every uplink. This module provides a family of jit-compatible *update
-codecs* applied to parameter deltas against the reference model the
-sender pulled (device→edge: the edge model at dispatch; edge→cloud: the
-global model):
+A family of jit-compatible *update codecs* applied to parameter deltas
+against the reference model the sender pulled (device→edge: the edge
+model at dispatch; edge→cloud: the global model):
 
-* ``none``       — identity; the parity oracle. Engines statically
-                   short-circuit to their exact uncompressed code path,
-                   so ``codec="none"`` reproduces it bitwise.
+* ``none``       — identity: the message is the sender's model itself,
+                   aggregated exactly as eqs. (2)/(3) read; it carries no
+                   state and no key, and traces no codec operation.
 * ``bf16_delta`` — casts the delta to bfloat16 (16 bits/param).
 * ``int8``       — stochastic-rounding quantization to int8 with one
                    per-tensor (per message, per leaf) f32 scale
@@ -18,8 +16,8 @@ global model):
                    (k = max(1, round(topk_frac·n))), sent as
                    (index, value) pairs.
 
-Each codec carries an **error-feedback residual** per sender (Seide et
-al. 2014 / Karimireddy et al. 2019): the encoder compresses
+Each lossy codec carries an **error-feedback residual** per sender
+(Seide et al. 2014 / Karimireddy et al. 2019): the encoder compresses
 ``x = delta + residual`` and keeps ``residual' = x - decode(encode(x))``
 for the next round, so the *accumulated* compression error stays bounded
 and compressed training remains unbiased over rounds (property-tested in
@@ -30,18 +28,26 @@ model charges: engines patch ``SystemParams.model_bits`` with it, so
 ``t_com``/``e_com``/``cloud_cost`` (eqs. (7)-(8), (11)-(12)) and the
 convex resource allocation all see the codec's actual bits-per-message.
 
+The engines never ask which codec is active. They thread the codec's
+state (:func:`init_state`) and key (:func:`round_key`, :func:`split`,
+:func:`fold_in`) through unconditionally — both are ``None`` for the
+identity codec — and ship every uplink through :func:`uplink`, then
+either aggregate the messages (:func:`aggregate`, eqs. (2)/(3) over a
+hop's :class:`Panel`) or buffer what the receiver reconstructs
+(:func:`receive`, the async edge buffers). A lossy message is each
+leaf's wire form ``(q, scale)``; the sync hops aggregate it in delta
+space, ``base + Σ w·decode(q, scale)``, and a kernel panel streams the
+undecoded q into ``kernels/hier_agg`` ``masked_decode_aggregate``.
+
 Encoding is row-wise: leaves carry a leading message axis (H devices or
 M edges) and every row is one message. ``encode_rows``/``decode_rows``
-are the single source of codec math; :func:`encode_decode` composes them
-over pytrees, and the kernel aggregation path consumes
-``encode_leaf``'s (q, scale) form directly (``kernels/hier_agg``
-``masked_decode_aggregate`` folds the scales into the in-kernel weight
-panel, so the dense decoded update matrix is never a matmul input).
+are the single source of codec math.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -53,8 +59,7 @@ CODECS = ("none", "bf16_delta", "int8", "topk")
 class CompressionConfig:
     """Uplink update-codec knobs (hashable — used as a static jit arg).
 
-    ``codec="none"`` is the identity oracle: engines skip the delta
-    transform entirely and trace their uncompressed program.
+    ``codec="none"`` is the identity codec (see the module docstring).
     ``error_feedback`` keeps a per-sender residual accumulator across
     rounds; ``seed`` feeds the stochastic-rounding key stream (derived
     per (lane, round), never carried — host-loop and fused-scan engines
@@ -76,6 +81,9 @@ class CompressionConfig:
     @property
     def active(self) -> bool:
         return self.codec != "none"
+
+
+IDENTITY = CompressionConfig()      # the default codec: none
 
 
 def _topk_k(cfg: CompressionConfig, n: int) -> int:
@@ -103,6 +111,13 @@ def message_bits(cfg: CompressionConfig, params) -> float:
         n = leaf.size
         bits += _topk_k(cfg, n) * (32 + max(1, math.ceil(math.log2(n))))
     return float(bits)
+
+
+def require_identity(cfg: CompressionConfig, reason: str) -> None:
+    """Refuse a lossy codec (``ValueError(reason)``) on a path that
+    ships raw payloads."""
+    if cfg.active:
+        raise ValueError(reason)
 
 
 def init_state(cfg: CompressionConfig, params, n_rows: int):
@@ -162,31 +177,97 @@ def encode_leaf(cfg: CompressionConfig, key, delta, resid):
     return q, scale, resid
 
 
-def encode_decode(cfg: CompressionConfig, key, delta, resid):
-    """Compress-then-decompress a pytree of updates with error feedback.
-
-    ``delta``/``resid``: pytrees whose leaves carry a leading message
-    axis (R, ...). Returns ``(decoded, new_resid)`` with the same
-    structure; the identity codec passes both through untouched.
-    """
-    if not cfg.active:
-        return delta, resid
-    d_leaves, treedef = jax.tree.flatten(delta)
-    r_leaves = jax.tree.leaves(resid)
-    keys = jax.random.split(key, len(d_leaves))
-    dec_leaves, new_r = [], []
-    for d, r, k in zip(d_leaves, r_leaves, keys):
-        R = d.shape[0]
-        q, s, nr = encode_leaf(cfg, k, d.reshape(R, -1).astype(jnp.float32),
-                               r.reshape(R, -1))
-        dec_leaves.append(decode_rows(cfg, q, s).reshape(d.shape))
-        new_r.append(nr.reshape(r.shape))
-    return treedef.unflatten(dec_leaves), treedef.unflatten(new_r)
-
-
-def round_key(cfg: CompressionConfig, lane_seed: int, round_idx):
+def round_key(cfg: CompressionConfig, lane_seed, round_idx):
     """Deterministic per-(lane, round) codec key — stateless, so the
     host-loop and fused-scan engines draw identical randomness without
-    threading a key through their carries."""
-    base = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), lane_seed)
-    return jax.random.fold_in(base, round_idx)
+    threading a key through their carries. ``lane_seed`` and
+    ``round_idx`` broadcast: arrays give a key per element. None for the
+    identity codec, which draws nothing."""
+    if not cfg.active:
+        return None
+    base = jax.random.PRNGKey(cfg.seed)
+    seeds, rounds = jnp.broadcast_arrays(lane_seed, round_idx)
+    fold = lambda s, r: jax.random.fold_in(  # noqa: E731
+        jax.random.fold_in(base, s), r)
+    for _ in range(seeds.ndim):
+        fold = jax.vmap(fold)
+    return fold(seeds, rounds)
+
+
+def split(key, n: int):
+    """``jax.random.split(key, n)``; None stays None (identity codec)."""
+    return None if key is None else jax.random.split(key, n)
+
+
+def fold_in(key, data):
+    """``jax.random.fold_in(key, data)``; None stays None."""
+    return None if key is None else jax.random.fold_in(key, data)
+
+
+def key_at(keys, i):
+    """``keys[i]`` of a :func:`split`; None stays None."""
+    return None if keys is None else keys[i]
+
+
+class Panel(NamedTuple):
+    """One hop's weight panel (eq. (2) per edge or eq. (3)), built once
+    by the engine: ``dense(rows)`` weighs (R, p) f32 message rows;
+    ``decoded(scale, q)`` weighs the rows ``decode_rows(q, scale)``
+    without forming them where the panel can (the ``hier_agg`` kernel)."""
+    dense: Callable
+    decoded: Callable
+
+
+def uplink(cfg: CompressionConfig, key, ref, new, resid):
+    """Each sender's message for its trained model ``new`` against the
+    reference ``ref`` it pulled, and its updated residual rows.
+
+    ``new``/``resid``: pytrees whose leaves carry a leading sender axis
+    (R, ...); ``ref`` the same per sender or, broadcast, one model.
+    Identity codec: ``(new, resid)``, no operation. Lossy: each leaf's
+    wire form ``(q (R, p), scale (R,))`` of ``new - ref + resid``, one
+    key of ``split(key, n_leaves)`` per leaf.
+    """
+    if not cfg.active:
+        return new, resid
+    leaves, treedef = jax.tree.flatten(new)
+    keys = jax.random.split(key, len(leaves))
+    msgs, resids = [], []
+    for n, r, e, k in zip(leaves, jax.tree.leaves(ref),
+                          jax.tree.leaves(resid), keys):
+        R = n.shape[0]
+        q, scale, e_new = encode_leaf(
+            cfg, k, (n - r).reshape(R, -1).astype(jnp.float32),
+            e.reshape(R, -1))
+        msgs.append((q, scale))
+        resids.append(e_new.reshape(e.shape))
+    return treedef.unflatten(msgs), treedef.unflatten(resids)
+
+
+def aggregate(cfg: CompressionConfig, panel: Panel, msg, base):
+    """Eqs. (2)/(3) over one hop's messages: f32 leaves shaped like
+    ``base``, the receivers' current models. Identity codec: the panel
+    weighs the senders' models. Lossy: ``base + Σ w·decode(msg)`` —
+    exactly the identity's average when the codec is lossless, as every
+    receiver's weights sum to 1 (an empty edge's to 0: it keeps
+    ``base``)."""
+    if not cfg.active:
+        return jax.tree.map(
+            lambda m, b: panel.dense(m.reshape(m.shape[0], -1))
+            .reshape(b.shape), msg, base)
+
+    def add(b, m):
+        d = panel.decoded(m[1], m[0])       # (R', p) or (p,) weighed rows
+        return (b.reshape(d.shape) + d).reshape(b.shape)
+    return jax.tree.map(add, base, msg)
+
+
+def receive(cfg: CompressionConfig, msg, ref):
+    """The model a receiver reconstructs from ``msg`` sent against
+    ``ref``: the message itself for the identity codec, ``ref +
+    decode(msg)`` in ``ref``'s dtype otherwise."""
+    if not cfg.active:
+        return msg
+    return jax.tree.map(
+        lambda r, m: (r + decode_rows(cfg, m[0], m[1]).reshape(r.shape))
+        .astype(r.dtype), ref, msg)

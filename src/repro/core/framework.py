@@ -56,12 +56,12 @@ if TYPE_CHECKING:
     from repro.models.spec import ModelSpec
 
 
-def round_step_core(apply_fn, sp: cm.SystemParams, params, u, D, p, g,
+def round_step_core(apply_fn, sp: cm.SystemParams, state, u, D, p, g,
                     g_cloud, B_m, X, y, mask, sizes, assign, lr, *,
                     M: int, L: int, Q: int, alloc_steps: int,
-                    agg_kernel: bool = False, codec=None,
-                    codec_state=None, codec_key=None, frozen=None,
-                    lane_chunk: int = 0):
+                    agg_kernel: bool = False,
+                    codec: comp.CompressionConfig = comp.IDENTITY,
+                    codec_key=None, frozen=None, lane_chunk: int = 0):
     """Traceable fused round: one global iteration minus scheduling.
 
     Inputs are pre-gathered for the scheduled cohort: u/D/p/sizes (H,),
@@ -71,18 +71,17 @@ def round_step_core(apply_fn, sp: cm.SystemParams, params, u, D, p, g,
     (d) Algorithm-1 training into one program. ``agg_kernel=True`` runs
     the hierarchical aggregation (2)-(3) through the fused masked-weight
     ``kernels/hier_agg`` Pallas kernel (interpret off-TPU) instead of
-    masked XLA einsums. Returns (new_params, (T_i, E_i, T_m, E_m, b, f)).
-    ``params`` is the trainable tree, ``frozen`` the payload's base (or
-    None), held once and read by local training alone, which trains
+    masked XLA einsums. ``state`` is ``(params, codec_state)``: the
+    trainable tree and the cohort's codec state (``(None, None)`` for
+    the identity codec); ``frozen`` is the payload's base (or None),
+    held once and read by local training alone, which trains
     ``lane_chunk`` lanes at a time (``cohort_local_sgd``; 0: all).
 
-    Compression: with an active ``codec`` (static
-    ``CompressionConfig``), pass the caller's ``sp`` already patched
-    with the codec's per-message bits (``compression.message_bits``) so
-    the allocation and eqs. (7)-(12) price the compressed payload;
-    ``codec_state`` is ``(dev_resid, edge_resid)`` error-feedback trees
-    for the cohort (H, ...) and the edges (M, ...). The return then
-    becomes ``(new_params, (new_dev_resid, new_edge_resid), aux)``.
+    ``codec``, the codec state and ``codec_key`` go to Algorithm 1 as
+    they are (``hfl_global_iteration_core``); pass ``sp`` patched with
+    the codec's per-message bits (``compression.message_bits``) so the
+    allocation and eqs. (7)-(12) price what the uplinks ship. Returns
+    ``(new_state, (T_i, E_i, T_m, E_m, b, f))``.
     """
     H = assign.shape[0]
     # the plan and its price, problem (27); training and aggregation
@@ -98,36 +97,29 @@ def round_step_core(apply_fn, sp: cm.SystemParams, params, u, D, p, g,
         g_sel = g[jnp.arange(H), assign]
         T_i, E_i, T_m, E_m = cm.round_cost_gathered(
             sp, u, D, p, g_sel, g_cloud, assign, b, f, M)
-    if codec is not None and codec.active:
-        dev_resid, edge_resid = codec_state
-        new_params, dev_resid, edge_resid = hfl_global_iteration_core(
-            apply_fn, params, X, y, mask, sizes, assign, M=M, L=L, Q=Q,
-            lr=lr, agg_kernel=agg_kernel, codec=codec,
-            dev_resid=dev_resid, edge_resid=edge_resid,
-            codec_key=codec_key, frozen=frozen, lane_chunk=lane_chunk)
-        return new_params, (dev_resid, edge_resid), (T_i, E_i, T_m, E_m,
-                                                     b, f)
-    new_params = hfl_global_iteration_core(
+    params, codec_state = state
+    trained = hfl_global_iteration_core(
         apply_fn, params, X, y, mask, sizes, assign, M=M, L=L, Q=Q, lr=lr,
-        agg_kernel=agg_kernel, frozen=frozen, lane_chunk=lane_chunk)
-    return new_params, (T_i, E_i, T_m, E_m, b, f)
+        agg_kernel=agg_kernel, codec=codec, codec_state=codec_state,
+        codec_key=codec_key, frozen=frozen, lane_chunk=lane_chunk)
+    return trained, (T_i, E_i, T_m, E_m, b, f)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "apply_fn", "sp", "M", "L", "Q", "alloc_steps", "agg_kernel", "codec",
     "lane_chunk"))
-def round_step(apply_fn, sp: cm.SystemParams, params, u, D, p, g, g_cloud,
+def round_step(apply_fn, sp: cm.SystemParams, state, u, D, p, g, g_cloud,
                B_m, X, y, mask, sizes, assign, lr, *, M: int, L: int,
                Q: int, alloc_steps: int, agg_kernel: bool = False,
-               codec=None, codec_state=None, codec_key=None, frozen=None,
-               lane_chunk: int = 0):
+               codec: comp.CompressionConfig = comp.IDENTITY,
+               codec_key=None, frozen=None, lane_chunk: int = 0):
     """Jitted fused round — see ``round_step_core``."""
-    return round_step_core(apply_fn, sp, params, u, D, p, g, g_cloud, B_m,
+    return round_step_core(apply_fn, sp, state, u, D, p, g, g_cloud, B_m,
                            X, y, mask, sizes, assign, lr,
                            M=M, L=L, Q=Q, alloc_steps=alloc_steps,
                            agg_kernel=agg_kernel, codec=codec,
-                           codec_state=codec_state, codec_key=codec_key,
-                           frozen=frozen, lane_chunk=lane_chunk)
+                           codec_key=codec_key, frozen=frozen,
+                           lane_chunk=lane_chunk)
 
 
 @dataclasses.dataclass
@@ -185,21 +177,19 @@ class HFLFramework:
         # (device->edge and edge->cloud ship the same codec), so the
         # round sp the allocator/cost model see carries them; identity
         # codec => uplink_bits == model_bits and sp_round == sp (the
-        # same jit cache entry — bitwise parity with the seed path).
+        # same jit cache entry).
         self.codec = cfg.compression
-        if self.codec.active and cfg.engine == "sequential":
-            raise ValueError("compression requires engine='fused' (the "
-                             "sequential oracle ships raw payloads)")
+        if cfg.engine == "sequential":
+            comp.require_identity(self.codec, "compression requires "
+                                  "engine='fused' (the sequential oracle "
+                                  "ships raw payloads)")
         self.uplink_bits = comp.message_bits(self.codec, self.model_params)
         self.sp_round = dataclasses.replace(
             self.sp, model_bits=float(self.uplink_bits))
-        self.codec_state = None
-        if self.codec.active:
-            self.codec_state = (
-                comp.init_state(self.codec, self.model_params,
-                                fed.n_devices),
-                comp.init_state(self.codec, self.model_params,
-                                pop.n_edges))
+        # error feedback: (device rows (N, ...), edge rows (M, ...))
+        self.codec_state = (
+            comp.init_state(self.codec, self.model_params, fed.n_devices),
+            comp.init_state(self.codec, self.model_params, pop.n_edges))
 
         self.X, self.y, self.mask = pad_device_data(fed)
         # (N,) valid samples per device, for the pad share
@@ -294,37 +284,24 @@ class HFLFramework:
                           pop.g_cloud, pop.B_m, self.X[sched],
                           self.y[sched], self.mask[sched], D,
                           jnp.asarray(assign))
-                if self.codec.active:
-                    dev_resid, edge_resid = self.codec_state
-                    cohort_resid = jax.tree.map(lambda r: r[sched],
-                                                dev_resid)
+                dev_resid, edge_resid = self.codec_state
+                cohort_resid = jax.tree.map(lambda r: r[sched], dev_resid)
             # the round program's dispatch; it runs on while the host
             # goes on to the evaluation, whose first chunk waits for it
             with TraceAnnotation("hfl.round_step", round=i):
-                if self.codec.active:
-                    (self.model_params, (cohort_resid, edge_resid),
-                     (T_i, E_i, _, _, _, _)) = round_step(
-                        self.apply_fn, self.sp_round, self.model_params,
-                        *cohort, self.cfg.lr,
-                        M=pop.n_edges, L=sp.L, Q=sp.Q,
-                        alloc_steps=self.cfg.alloc_steps,
-                        agg_kernel=self.cfg.agg_kernel, codec=self.codec,
-                        codec_state=(cohort_resid, edge_resid),
-                        codec_key=comp.round_key(self.codec, self.cfg.seed,
-                                                 i), frozen=self.frozen,
-                        lane_chunk=self.spec.lane_chunk)
-                    self.codec_state = (
-                        jax.tree.map(lambda full, nr: full.at[sched].set(nr),
-                                     dev_resid, cohort_resid),
-                        edge_resid)
-                else:
-                    self.model_params, (T_i, E_i, _, _, _, _) = round_step(
-                        self.apply_fn, sp, self.model_params,
-                        *cohort, self.cfg.lr,
-                        M=pop.n_edges, L=sp.L, Q=sp.Q,
-                        alloc_steps=self.cfg.alloc_steps,
-                        agg_kernel=self.cfg.agg_kernel, frozen=self.frozen,
-                        lane_chunk=self.spec.lane_chunk)
+                ((self.model_params, (cohort_resid, edge_resid)),
+                 (T_i, E_i, _, _, _, _)) = round_step(
+                    self.apply_fn, self.sp_round,
+                    (self.model_params, (cohort_resid, edge_resid)),
+                    *cohort, self.cfg.lr, M=pop.n_edges, L=sp.L, Q=sp.Q,
+                    alloc_steps=self.cfg.alloc_steps,
+                    agg_kernel=self.cfg.agg_kernel, codec=self.codec,
+                    codec_key=comp.round_key(self.codec, self.cfg.seed, i),
+                    frozen=self.frozen, lane_chunk=self.spec.lane_chunk)
+                self.codec_state = (
+                    jax.tree.map(lambda full, nr: full.at[sched].set(nr),
+                                 dev_resid, cohort_resid),
+                    edge_resid)
 
         with TraceAnnotation("hfl.eval", round=i):
             acc = self.spec.eval_fn(self.model_params, self.fed.X_test,
@@ -378,7 +355,7 @@ class HFLFramework:
             jnp.asarray(b), jnp.asarray(f))
 
         # Algorithm 1
-        self.model_params = hfl_global_iteration(
+        self.model_params, _ = hfl_global_iteration(
             self.apply_fn, self.model_params,
             self.X[sched], self.y[sched], self.mask[sched],
             self.pop.D[sched], jnp.asarray(assign),

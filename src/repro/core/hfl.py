@@ -17,6 +17,8 @@ weight panel in-kernel from the one-hot + device sizes (interpret mode
 off-TPU). Both backends share the empty-edge fixup (edges with no
 devices keep their model) and the eq.-(3) weights; the einsum path is
 the parity oracle (``tests/test_kernels.py`` / ``test_round_engine.py``).
+Both uplinks go through a ``core.compression`` codec in one scan body;
+the identity codec (the default) sends the models themselves.
 """
 from __future__ import annotations
 
@@ -28,8 +30,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.core import compression as comp
 from repro.core.local_train import apply_model, cohort_local_sgd
 from repro.data.partition import FederatedData
+
 
 def agg_matmul(a, b):
     """``a @ b`` at full f32 precision on every backend.
@@ -39,6 +43,15 @@ def agg_matmul(a, b):
     what an f32 matmul gets on TPU by default (the CPU computes f32
     either way)."""
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+def einsum_panel(codec, w) -> comp.Panel:
+    """One hop's weights ``w`` ((M, H) per edge, or (M,) for the cloud)
+    as a :class:`~repro.core.compression.Panel` on the einsum route: the
+    codec's messages are decoded densely, then weighed."""
+    return comp.Panel(
+        functools.partial(agg_matmul, w),
+        lambda scale, q: agg_matmul(w, comp.decode_rows(codec, q, scale)))
 
 
 def pad_device_data(fed: FederatedData, Dmax: Optional[int] = None):
@@ -64,9 +77,9 @@ def pad_device_data(fed: FederatedData, Dmax: Optional[int] = None):
 def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
                               sizes, assign, *, M: int, L: int, Q: int,
                               lr: float, agg_kernel: bool = False,
-                              codec=None, dev_resid=None, edge_resid=None,
-                              codec_key=None, frozen=None,
-                              lane_chunk: int = 0):
+                              codec: comp.CompressionConfig = comp.IDENTITY,
+                              codec_state=(None, None), codec_key=None,
+                              frozen=None, lane_chunk: int = 0):
     """Algorithm 1, traceable core (no jit) — inlined by the fused round
     engine (``framework.round_step``) and vmapped by ``core.sweep``.
 
@@ -80,26 +93,19 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
     assign: (H,) edge ids. ``agg_kernel=True`` routes eqs. (2)-(3)
     through the fused masked-weight Pallas kernel (the one-hot + sizes go
     in raw; the normalised weight panel is built in-kernel, and vmapped
-    callers hit the lane-batched grid). Returns new global params.
+    callers hit the lane-batched grid).
 
-    With an active ``codec`` (:class:`repro.core.compression.
-    CompressionConfig`, a static arg), both uplinks are compressed:
-    devices encode their post-SGD delta vs the edge model they pulled
-    and edges aggregate the decoded deltas in delta space
-    (``edge' = edge + Σ w·decode(encode(delta))``, exactly eq. (2) when
-    the codec is lossless); after Q edge iterations each edge encodes
-    its delta vs the global model for the cloud hop. ``dev_resid``
-    ((H, ...) cohort-gathered) and ``edge_resid`` ((M, ...)) are the
-    error-feedback accumulators, updated every message; ``codec_key``
-    seeds stochastic rounding. Returns
-    ``(new_params, new_dev_resid, new_edge_resid)`` in this mode —
-    ``codec=None`` / ``codec="none"`` keeps the uncompressed trace (and
-    the single-value return) bit-for-bit.
+    Both uplinks go through ``codec`` (a static
+    :class:`repro.core.compression.CompressionConfig`): devices send
+    their trained model against the edge model they pulled, edges theirs
+    against the global model after Q edge iterations.
+    ``codec_state`` is the ``(dev_resid (H, ...), edge_resid (M, ...))``
+    error-feedback pair and ``codec_key`` the round's key — ``(None,
+    None)`` and None for the identity codec. Returns
+    ``(new_params, (dev_resid, edge_resid))``.
     """
-    compress = codec is not None and codec.active
-    if compress:
-        from repro.core import compression as comp
     H = sizes.shape[0]
+    dev_resid, edge_resid = codec_state
     # named scopes: local_train (cohort_local_sgd and the pull of edge
     # models) and aggregate (eqs. (2)-(3), their weights, the codec)
     with jax.named_scope("aggregate"):
@@ -111,116 +117,67 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
         if agg_kernel:
             from repro.kernels.hier_agg.ops import (masked_aggregate,
                                                     masked_decode_aggregate)
-            # eq. (2): panel built in-kernel from membership rows + sizes
-            edge_aggregate = functools.partial(masked_aggregate, onehot.T,
-                                               w_dev)
+            # eq. (2): panel built in-kernel from membership rows + sizes;
+            # a lossy codec's scales fold into it, q streams in undecoded
+            edge_panel = comp.Panel(
+                functools.partial(masked_aggregate, onehot.T, w_dev),
+                functools.partial(masked_decode_aggregate, onehot.T, w_dev))
             # eq. (3) = the same kernel with an all-ones (1, M) mask over
             # the per-edge cohort sizes D_{N_m} (empty edges weigh 0)
-            cloud_aggregate = lambda flat: masked_aggregate(  # noqa: E731
-                jnp.ones((1, M), jnp.float32), edge_tot, flat)[0]
-            # compression path: scales fold into the in-kernel panel, the
-            # wire-format q streams into the MXU undecoded
-            edge_dec_aggregate = functools.partial(
-                masked_decode_aggregate, onehot.T, w_dev)
-            cloud_dec_aggregate = lambda sc, q: masked_decode_aggregate(  # noqa: E731
-                jnp.ones((1, M), jnp.float32), edge_tot, sc, q)[0]
+            ones = jnp.ones((1, M), jnp.float32)
+            cloud_panel = comp.Panel(
+                lambda rows: masked_aggregate(ones, edge_tot, rows)[0],
+                lambda sc, q: masked_decode_aggregate(ones, edge_tot, sc,
+                                                      q)[0])
         else:
             # per-edge normalised device weights: (M, H)
             w_edge = (onehot.T * w_dev[None, :]) \
                 / jnp.maximum(edge_tot, 1.0)[:, None]
             w_cloud = jnp.where(has_dev, edge_tot, 0.0)
             w_cloud = w_cloud / jnp.maximum(jnp.sum(w_cloud), 1.0)
-            edge_aggregate = functools.partial(agg_matmul, w_edge)
-            cloud_aggregate = functools.partial(agg_matmul, w_cloud)
-            if compress:
-                # einsum decode-aggregate oracle: dense decode, then matmul
-                edge_dec_aggregate = lambda sc, q: agg_matmul(  # noqa: E731
-                    w_edge, comp.decode_rows(codec, q, sc))
-                cloud_dec_aggregate = lambda sc, q: agg_matmul(  # noqa: E731
-                    w_cloud, comp.decode_rows(codec, q, sc))
+            edge_panel = einsum_panel(codec, w_edge)
+            cloud_panel = einsum_panel(codec, w_cloud)
+        keys = comp.split(codec_key, Q + 1)     # stochastic rounding
 
     # edge models start from the global model
     edge_params = jax.tree.map(
         lambda g: jnp.broadcast_to(g[None], (M,) + g.shape), global_params)
 
-    if not compress:
-        def edge_iter(edge_params, _):
-            # each device pulls its edge's model
-            with jax.named_scope("local_train"):
-                dev_params = jax.tree.map(
-                    lambda e: jnp.take(e, assign, axis=0), edge_params)
-            dev_params = cohort_local_sgd(apply_fn, dev_params, X, y, mask,
-                                          L, lr, frozen, lane_chunk)
-            # (2): weighted average per edge; empty edges keep their model
-            # (aggregate in f32, carry the model dtype through the scan)
-            def agg(delta, old):
-                flat = delta.reshape(H, -1)
-                new = edge_aggregate(flat).reshape((M,) + delta.shape[1:])
-                keep = has_dev.reshape((M,) + (1,) * (delta.ndim - 1))
-                return jnp.where(keep, new, old).astype(old.dtype)
-            with jax.named_scope("aggregate"):
-                new_edge = jax.tree.map(agg, dev_params, edge_params)
-            return new_edge, None
-
-        edge_params, _ = jax.lax.scan(edge_iter, edge_params, None, length=Q)
-
-        # (3): cloud aggregation, weights D_{N_m} (empty edges weight 0)
-        def cloud_agg(e):
-            flat = e.reshape(M, -1)
-            return cloud_aggregate(flat).reshape(e.shape[1:]).astype(e.dtype)
-
-        with jax.named_scope("aggregate"):
-            return jax.tree.map(cloud_agg, edge_params)
-
-    # ---- compressed path: both uplinks ship encoded deltas; aggregation
-    #      runs in delta space (edge' = edge + Σ w·decoded_delta, exactly
-    #      eq. (2) for a lossless codec since the weights sum to 1 per
-    #      non-empty edge — empty edges get zero weight mass and keep
-    #      their model automatically).
-    with jax.named_scope("aggregate"):     # stochastic-rounding keys
-        keys = jax.random.split(codec_key, Q + 1)
-
-    def edge_iter_c(carry, k_round):
-        edge_params, resid = carry
+    def edge_iter(carry, key):
+        edge_params, dev_resid = carry
+        # each device pulls its edge's model
         with jax.named_scope("local_train"):
             pulled = jax.tree.map(lambda e: jnp.take(e, assign, axis=0),
                                   edge_params)
         trained = cohort_local_sgd(apply_fn, pulled, X, y, mask, L, lr,
                                    frozen, lane_chunk)
-        t_leaves, treedef = jax.tree.flatten(trained)
-        p_leaves = jax.tree.leaves(pulled)
-        r_leaves = jax.tree.leaves(resid)
-        e_leaves = jax.tree.leaves(edge_params)
-        new_e, new_r = [], []
+        # (2): weighted average per edge; empty edges keep their model
+        # (aggregate in f32, carry the model dtype through the scan)
+        def keep(new, old):
+            on = has_dev.reshape((M,) + (1,) * (old.ndim - 1))
+            return jnp.where(on, new, old).astype(old.dtype)
         with jax.named_scope("aggregate"):
-            ks = jax.random.split(k_round, len(t_leaves))
-            for t, p_, r, e, k in zip(t_leaves, p_leaves, r_leaves,
-                                      e_leaves, ks):
-                d = (t - p_).reshape(H, -1).astype(jnp.float32)
-                q, sc, nr = comp.encode_leaf(codec, k, d, r.reshape(H, -1))
-                dm = edge_dec_aggregate(sc, q)                # (M, p)
-                ef = e.reshape(M, -1) + dm
-                new_e.append(ef.reshape(e.shape).astype(e.dtype))
-                new_r.append(nr.reshape(r.shape))
-        return (treedef.unflatten(new_e), treedef.unflatten(new_r)), None
+            msg, dev_resid = comp.uplink(codec, key, pulled, trained,
+                                         dev_resid)
+            new_edge = jax.tree.map(
+                keep, comp.aggregate(codec, edge_panel, msg, edge_params),
+                edge_params)
+        return (new_edge, dev_resid), None
 
     (edge_params, dev_resid), _ = jax.lax.scan(
-        edge_iter_c, (edge_params, dev_resid), keys[:Q])
+        edge_iter, (edge_params, dev_resid),
+        comp.key_at(keys, slice(None, Q)), length=Q)
 
-    # cloud hop: each edge encodes its delta vs the global model (3)
-    e_leaves, treedef = jax.tree.flatten(edge_params)
-    g_leaves = jax.tree.leaves(global_params)
-    r_leaves = jax.tree.leaves(edge_resid)
-    new_g, new_r = [], []
+    # (3): cloud aggregation, weights D_{N_m} (empty edges weight 0)
     with jax.named_scope("aggregate"):
-        ks = jax.random.split(keys[Q], len(e_leaves))
-        for e, g, r, k in zip(e_leaves, g_leaves, r_leaves, ks):
-            d = (e.reshape(M, -1) - g.reshape(1, -1)).astype(jnp.float32)
-            q, sc, nr = comp.encode_leaf(codec, k, d, r.reshape(M, -1))
-            gf = g.reshape(-1) + cloud_dec_aggregate(sc, q)
-            new_g.append(gf.reshape(g.shape).astype(g.dtype))
-            new_r.append(nr.reshape(r.shape))
-    return (treedef.unflatten(new_g), dev_resid, treedef.unflatten(new_r))
+        msg, edge_resid = comp.uplink(codec, comp.key_at(keys, Q),
+                                      global_params, edge_params,
+                                      edge_resid)
+        new_params = jax.tree.map(
+            lambda n, g: n.astype(g.dtype),
+            comp.aggregate(codec, cloud_panel, msg, global_params),
+            global_params)
+    return new_params, (dev_resid, edge_resid)
 
 
 @functools.partial(jax.jit, static_argnames=("apply_fn", "M", "L", "Q",
@@ -229,14 +186,14 @@ def hfl_global_iteration_core(apply_fn: Callable, global_params, X, y, mask,
 def hfl_global_iteration(apply_fn: Callable, global_params, X, y, mask,
                          sizes, assign, *, M: int, L: int, Q: int,
                          lr: float, agg_kernel: bool = False,
-                         codec=None, dev_resid=None, edge_resid=None,
-                         codec_key=None, frozen=None, lane_chunk: int = 0):
+                         codec: comp.CompressionConfig = comp.IDENTITY,
+                         codec_state=(None, None), codec_key=None,
+                         frozen=None, lane_chunk: int = 0):
     """Jitted Algorithm 1 — see ``hfl_global_iteration_core``."""
     return hfl_global_iteration_core(apply_fn, global_params, X, y, mask,
                                      sizes, assign, M=M, L=L, Q=Q, lr=lr,
                                      agg_kernel=agg_kernel, codec=codec,
-                                     dev_resid=dev_resid,
-                                     edge_resid=edge_resid,
+                                     codec_state=codec_state,
                                      codec_key=codec_key, frozen=frozen,
                                      lane_chunk=lane_chunk)
 

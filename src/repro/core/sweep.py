@@ -115,87 +115,57 @@ def build_scheduler(name: str, fed: FederatedData, sp: cm.SystemParams,
 
 def _sweep_round_lanes(apply_fn, sp: cm.SystemParams, params_b, u_b, D_b,
                        p_b, g_b, g_cloud_b, B_m_b, X_b, y_b, mask_b,
-                       sizes_b, sched_b, assign_b, lr, done_b, *, M: int,
-                       L: int, Q: int, alloc_steps: int, train_only: bool,
-                       agg_kernel: bool, lane_chunk: Optional[int] = None,
-                       codec=None, codec_state_b=None, codec_keys_b=None):
+                       sizes_b, sched_b, assign_b, lr, done_b,
+                       codec_state_b, codec_keys_b, *, M: int, L: int,
+                       Q: int, alloc_steps: int, train_only: bool,
+                       agg_kernel: bool, codec: comp.CompressionConfig,
+                       lane_chunk: Optional[int] = None):
     """Traceable lane-vmapped round body shared by the single-device
     ``sweep_round`` jit and the ``shard_map`` blocks of
     ``sweep_round_sharded`` (each device runs this on its lane block).
 
-    lane_chunk: None vmaps the whole lane axis into one batched program
-    (the PR-1 layout, right for MXU-rich hardware). An int processes the
-    lanes sequentially in vmapped chunks of that size via ``lax.map`` —
-    on CPU hosts the small per-chunk working set stays cache-resident
-    and XLA stops batch-fusing the tiny per-lane ops into bandwidth-
-    bound monsters, which measures 1.8-2.4x by itself at S=128 across
-    runs (see ``BENCH_sweep_shard.json``); must divide the lane-axis
-    length.
+    lane_chunk: None vmaps the whole lane axis into one batched program.
+    An int processes the lanes sequentially in vmapped chunks of that
+    size via ``lax.map``; it must divide the lane-axis length.
 
-    With an active ``codec`` the compressed round engine runs instead:
-    ``codec_state_b`` is ``(dev_resid (S, N, ...), edge_resid
-    (S, M, ...))`` error-feedback trees (cohort rows gathered/scattered
-    per lane, frozen on done lanes like the params), ``codec_keys_b``
-    (S, 2) per-lane round keys, and the return gains a third element —
-    the updated state. Inactive codec keeps the seed trace untouched.
+    ``codec_state_b`` is the lane-stacked ``(dev_resid (S, N, ...),
+    edge_resid (S, M, ...))`` error-feedback pair (cohort rows gathered
+    and scattered per lane, frozen on done lanes like the params) and
+    ``codec_keys_b`` the (S, 2) per-lane round keys — ``(None, None)``
+    and None for the identity codec. Returns ``(params_b, (T_i, E_i),
+    codec_state_b)``.
     """
-    codec_on = codec is not None and codec.active
-
     def one(params, u, D, p, g, g_cloud, B_m, X, y, mask, sizes, sched,
-            assign, done, *cstate):
-        if codec_on:
-            dev_resid, edge_resid, ckey = cstate
-            cohort_resid = jax.tree.map(lambda r: r[sched], dev_resid)
+            assign, done, cstate, ckey):
+        dev_resid, edge_resid = cstate
+        cohort_state = (jax.tree.map(lambda r: r[sched], dev_resid),
+                        edge_resid)
+        kw = dict(M=M, L=L, Q=Q, agg_kernel=agg_kernel, codec=codec,
+                  codec_key=ckey)
         if train_only:
-            if codec_on:
-                new_params, cohort_resid, new_edge_resid = \
-                    hfl_global_iteration_core(
-                        apply_fn, params, X[sched], y[sched], mask[sched],
-                        sizes[sched], assign, M=M, L=L, Q=Q, lr=lr,
-                        agg_kernel=agg_kernel, codec=codec,
-                        dev_resid=cohort_resid, edge_resid=edge_resid,
-                        codec_key=ckey)
-            else:
-                new_params = hfl_global_iteration_core(
-                    apply_fn, params, X[sched], y[sched], mask[sched],
-                    sizes[sched], assign, M=M, L=L, Q=Q, lr=lr,
-                    agg_kernel=agg_kernel)
+            trained = hfl_global_iteration_core(
+                apply_fn, params, X[sched], y[sched], mask[sched],
+                sizes[sched], assign, lr=lr, codec_state=cohort_state, **kw)
             zero = jnp.zeros(())
             T_i, E_i = zero, zero
-        elif codec_on:
-            new_params, (cohort_resid, new_edge_resid), \
-                (T_i, E_i, _, _, _, _) = round_step_core(
-                    apply_fn, sp, params, u[sched], D[sched], p[sched],
-                    g[sched], g_cloud, B_m, X[sched], y[sched],
-                    mask[sched], sizes[sched], assign, lr, M=M, L=L, Q=Q,
-                    alloc_steps=alloc_steps, agg_kernel=agg_kernel,
-                    codec=codec, codec_state=(cohort_resid, edge_resid),
-                    codec_key=ckey)
         else:
-            new_params, (T_i, E_i, _, _, _, _) = round_step_core(
-                apply_fn, sp, params, u[sched], D[sched], p[sched],
-                g[sched], g_cloud, B_m, X[sched], y[sched], mask[sched],
-                sizes[sched], assign, lr, M=M, L=L, Q=Q,
-                alloc_steps=alloc_steps, agg_kernel=agg_kernel)
-        new_params = jax.tree.map(
-            lambda old, new: jnp.where(done, old, new), params, new_params)
-        costs = (jnp.where(done, 0.0, T_i), jnp.where(done, 0.0, E_i))
-        if not codec_on:
-            return new_params, costs
+            trained, (T_i, E_i, _, _, _, _) = round_step_core(
+                apply_fn, sp, (params, cohort_state), u[sched], D[sched],
+                p[sched], g[sched], g_cloud, B_m, X[sched], y[sched],
+                mask[sched], sizes[sched], assign, lr,
+                alloc_steps=alloc_steps, **kw)
+        new_params, (cohort_resid, new_edge_resid) = trained
+        new_state = (jax.tree.map(lambda full, nr: full.at[sched].set(nr),
+                                  dev_resid, cohort_resid), new_edge_resid)
+        # a done lane keeps its model and residuals and costs nothing
         freeze = functools.partial(
             jax.tree.map, lambda old, new: jnp.where(done, old, new))
-        new_dev_resid = freeze(
-            dev_resid, jax.tree.map(
-                lambda full, nr: full.at[sched].set(nr), dev_resid,
-                cohort_resid))
-        return new_params, costs, (new_dev_resid,
-                                   freeze(edge_resid, new_edge_resid))
+        costs = (jnp.where(done, 0.0, T_i), jnp.where(done, 0.0, E_i))
+        return freeze(params, new_params), costs, freeze(cstate, new_state)
 
     lane_in = (params_b, u_b, D_b, p_b, g_b, g_cloud_b, B_m_b, X_b, y_b,
-               mask_b, sizes_b, sched_b, assign_b, done_b)
-    if codec_on:
-        lane_in = lane_in + (codec_state_b[0], codec_state_b[1],
-                             codec_keys_b)
+               mask_b, sizes_b, sched_b, assign_b, done_b, codec_state_b,
+               codec_keys_b)
     if lane_chunk is None:
         return jax.vmap(one)(*lane_in)
     n = sched_b.shape[0]
@@ -218,15 +188,16 @@ def sweep_round(apply_fn, sp: cm.SystemParams, params_b, u_b, D_b, p_b,
                 assign_b, lr, *, M: int, L: int, Q: int, alloc_steps: int,
                 train_only: bool = False, agg_kernel: bool = False,
                 lane_chunk: Optional[int] = None, done_b=None,
-                codec=None, codec_state_b=None, codec_keys_b=None):
+                codec: comp.CompressionConfig = comp.IDENTITY,
+                codec_state_b=(None, None), codec_keys_b=None):
     """One fused round for S lanes at once.
 
     Population/data arrays carry a leading lane axis (S, ...); sched_b
     and assign_b are (S, H); sizes_b (S, N) holds the Algorithm-1
     aggregation weights. Gathers each lane's cohort and vmaps
-    ``round_step_core``, returning (params_b, (T_i, E_i)) with (S,)
-    cost vectors. train_only=True skips resource allocation and cost
-    bookkeeping entirely (accuracy-only sweeps like Fig. 3/4) and
+    ``round_step_core``, returning (params_b, (T_i, E_i), codec_state_b)
+    with (S,) cost vectors. train_only=True skips resource allocation and
+    cost bookkeeping entirely (accuracy-only sweeps like Fig. 3/4) and
     returns zero costs. agg_kernel=True routes every lane's Algorithm-1
     aggregation through the lane-batched ``hier_agg`` Pallas kernel —
     the vmap hits the kernel's ``custom_vmap`` rule, so all S lanes
@@ -235,18 +206,17 @@ def sweep_round(apply_fn, sp: cm.SystemParams, params_b, u_b, D_b, p_b,
     of lanes that already reached the sweep's accuracy target — a done
     lane's model is frozen (params pass through unchanged) and it stops
     accruing training compute (its T_i/E_i come back zero), so finished
-    lanes no longer distort the sweep's cost totals. lane_chunk: see
-    ``_sweep_round_lanes`` — cache-blocked sequential chunks for CPU
-    hosts, None (one vmapped program) for accelerators.
+    lanes no longer distort the sweep's cost totals. lane_chunk and the
+    codec operands: see ``_sweep_round_lanes``.
     """
     if done_b is None:
         done_b = jnp.zeros((sched_b.shape[0],), bool)
     return _sweep_round_lanes(
         apply_fn, sp, params_b, u_b, D_b, p_b, g_b, g_cloud_b, B_m_b, X_b,
-        y_b, mask_b, sizes_b, sched_b, assign_b, lr, done_b, M=M, L=L, Q=Q,
-        alloc_steps=alloc_steps, train_only=train_only,
-        agg_kernel=agg_kernel, lane_chunk=lane_chunk, codec=codec,
-        codec_state_b=codec_state_b, codec_keys_b=codec_keys_b)
+        y_b, mask_b, sizes_b, sched_b, assign_b, lr, done_b, codec_state_b,
+        codec_keys_b, M=M, L=L, Q=Q, alloc_steps=alloc_steps,
+        train_only=train_only, agg_kernel=agg_kernel, codec=codec,
+        lane_chunk=lane_chunk)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -258,7 +228,8 @@ def sweep_round_sharded(apply_fn, sp: cm.SystemParams, params_b, u_b, D_b,
                         Q: int, alloc_steps: int, mesh,
                         train_only: bool = False, agg_kernel: bool = False,
                         lane_chunk: Optional[int] = None, done_b=None,
-                        codec=None, codec_state_b=None, codec_keys_b=None):
+                        codec: comp.CompressionConfig = comp.IDENTITY,
+                        codec_state_b=(None, None), codec_keys_b=None):
     """``sweep_round`` laid out over a 1-D ``Mesh(("lane",))``.
 
     Same args/semantics as ``sweep_round`` plus a static ``mesh``
@@ -273,36 +244,22 @@ def sweep_round_sharded(apply_fn, sp: cm.SystemParams, params_b, u_b, D_b,
     hfel/drl assignment hooks shard-compatible (their jitted searches
     run on the default device *between* sharded rounds). lane_chunk
     applies *within* each device's lane block (must divide S/d; see
-    ``_sweep_round_lanes`` for when to use it).
+    ``_sweep_round_lanes``).
     """
     if done_b is None:
         done_b = jnp.zeros((sched_b.shape[0],), bool)
     lane, rep = PartitionSpec("lane"), PartitionSpec()
-    codec_on = codec is not None and codec.active
-
-    def block(params_b, u_b, D_b, p_b, g_b, g_cloud_b, B_m_b, X_b, y_b,
-              mask_b, sizes_b, sched_b, assign_b, lr, done_b, *cstate):
-        kw = {}
-        if codec_on:
-            kw = dict(codec=codec, codec_state_b=(cstate[0], cstate[1]),
-                      codec_keys_b=cstate[2])
-        return _sweep_round_lanes(
-            apply_fn, sp, params_b, u_b, D_b, p_b, g_b, g_cloud_b, B_m_b,
-            X_b, y_b, mask_b, sizes_b, sched_b, assign_b, lr, done_b,
-            M=M, L=L, Q=Q, alloc_steps=alloc_steps, train_only=train_only,
-            agg_kernel=agg_kernel, lane_chunk=lane_chunk, **kw)
-
-    in_specs = (lane,) * 13 + (rep, lane)
-    out_specs = (lane, (lane, lane))
-    args = (params_b, u_b, D_b, p_b, g_b, g_cloud_b, B_m_b, X_b, y_b,
-            mask_b, sizes_b, sched_b, assign_b, lr, done_b)
-    if codec_on:
-        in_specs = in_specs + (lane, lane, lane)
-        out_specs = (lane, (lane, lane), (lane, lane))
-        args = args + (codec_state_b[0], codec_state_b[1], codec_keys_b)
-    sharded = jax.shard_map(block, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False)
-    return sharded(*args)
+    block = functools.partial(
+        _sweep_round_lanes, apply_fn, sp, M=M, L=L, Q=Q,
+        alloc_steps=alloc_steps, train_only=train_only,
+        agg_kernel=agg_kernel, codec=codec, lane_chunk=lane_chunk)
+    sharded = jax.shard_map(block, mesh=mesh,
+                            in_specs=(lane,) * 13 + (rep,) + (lane,) * 3,
+                            out_specs=(lane, (lane, lane), lane),
+                            check_vma=False)
+    return sharded(params_b, u_b, D_b, p_b, g_b, g_cloud_b, B_m_b, X_b,
+                   y_b, mask_b, sizes_b, sched_b, assign_b, lr, done_b,
+                   codec_state_b, codec_keys_b)
 
 
 def _sweep_eval_lanes(apply_fn, params_b, Xt_b, yt_b):
@@ -330,12 +287,12 @@ def _sweep_scan_lanes(apply_fn, sp, sp_assign, params_b, u_b, D_b, p_b,
                       g_b, g_cloud_b, B_m_b, X_b, y_b, mask_b, sizes_b,
                       dev_pos_b, edge_pos_b, Xt_b, yt_b, sched_rs,
                       sched_state_b, assign_keys_b, done_b, drl_params, lr,
-                      codec_state_b, codec_base_b, codec_r0,
+                      codec_state_b, codec_keys_rs,
                       *, M: int, L: int, Q: int, alloc_steps: int,
                       train_only: bool, agg_kernel: bool,
                       lane_chunk: Optional[int], assign: str, hfel_cfg,
                       target_acc: Optional[float], n_rounds: int,
-                      traced_sched, codec=None):
+                      traced_sched, codec: comp.CompressionConfig):
     """Traceable R-round S-lane sweep body: ``lax.scan`` over rounds of
     (scheduler step -> traced assignment -> lane-vmapped round body ->
     in-scan eval -> done-mask update). Shared by the single-device
@@ -353,18 +310,14 @@ def _sweep_scan_lanes(apply_fn, sp, sp_assign, params_b, u_b, D_b, p_b,
     The done-mask semantics mirror the host loop exactly: a lane's
     round outputs are recorded, then its done flag absorbs
     ``acc >= target_acc``, freezing it from the NEXT round on.
+    ``codec_state_b`` is carried; ``codec_keys_rs`` (R, S, 2) holds the
+    per-lane ``compression.round_key`` of every round, the stream the
+    host loop draws (None for the identity codec).
 
-    Returns ((params_b, done_b, sched_state_b, assign_keys_b),
-    (acc (R, S), T_i (R, S), E_i (R, S))).
-
-    With an active ``codec`` the carry additionally holds the per-lane
-    error-feedback state ``codec_state_b`` and a round counter (seeded
-    at ``codec_r0``) — codec keys are re-derived in-scan as
-    ``fold_in(codec_base_b[lane], round)``, the exact stream the host
-    loop draws, so fused and host compressed sweeps stay in lockstep.
+    Returns ((params_b, done_b, sched_state_b, assign_keys_b,
+    codec_state_b), (acc (R, S), T_i (R, S), E_i (R, S))).
     """
     hfel_kw = dict(hfel_cfg) if hfel_cfg is not None else None
-    codec_on = codec is not None and codec.active
 
     def assign_lane(u, D, p, g, g_cloud, B_m, dev_pos, edge_pos, sched,
                     key):
@@ -380,14 +333,9 @@ def _sweep_scan_lanes(apply_fn, sp, sp_assign, params_b, u_b, D_b, p_b,
         return a
 
     def step(carry, xs):
-        if codec_on:
-            (params_b, done_b, sched_state_b, keys_b, codec_state_b,
-             r) = carry
-        else:
-            params_b, done_b, sched_state_b, keys_b = carry
-        if traced_sched is None:
-            sched_b = xs
-        else:
+        params_b, done_b, sched_state_b, keys_b, codec_state_b = carry
+        sched_b, ckeys_b = xs
+        if traced_sched is not None:
             sched_state_b, sched_b = jax.vmap(traced_sched.step)(
                 sched_state_b)
         splits = jax.vmap(jax.random.split)(keys_b)        # (S, 2, 2)
@@ -395,37 +343,21 @@ def _sweep_scan_lanes(apply_fn, sp, sp_assign, params_b, u_b, D_b, p_b,
         assign_b = jax.vmap(assign_lane)(
             u_b, D_b, p_b, g_b, g_cloud_b, B_m_b, dev_pos_b, edge_pos_b,
             sched_b, sub_b)
-        if codec_on:
-            ckeys_b = jax.vmap(
-                lambda k: jax.random.fold_in(k, r))(codec_base_b)
-            new_params, (T_i, E_i), codec_state_b = _sweep_round_lanes(
-                apply_fn, sp, params_b, u_b, D_b, p_b, g_b, g_cloud_b,
-                B_m_b, X_b, y_b, mask_b, sizes_b, sched_b, assign_b, lr,
-                done_b, M=M, L=L, Q=Q, alloc_steps=alloc_steps,
-                train_only=train_only, agg_kernel=agg_kernel,
-                lane_chunk=lane_chunk, codec=codec,
-                codec_state_b=codec_state_b, codec_keys_b=ckeys_b)
-        else:
-            new_params, (T_i, E_i) = _sweep_round_lanes(
-                apply_fn, sp, params_b, u_b, D_b, p_b, g_b, g_cloud_b,
-                B_m_b, X_b, y_b, mask_b, sizes_b, sched_b, assign_b, lr,
-                done_b, M=M, L=L, Q=Q, alloc_steps=alloc_steps,
-                train_only=train_only, agg_kernel=agg_kernel,
-                lane_chunk=lane_chunk)
+        new_params, (T_i, E_i), codec_state_b = _sweep_round_lanes(
+            apply_fn, sp, params_b, u_b, D_b, p_b, g_b, g_cloud_b, B_m_b,
+            X_b, y_b, mask_b, sizes_b, sched_b, assign_b, lr, done_b,
+            codec_state_b, ckeys_b, M=M, L=L, Q=Q, alloc_steps=alloc_steps,
+            train_only=train_only, agg_kernel=agg_kernel, codec=codec,
+            lane_chunk=lane_chunk)
         acc = _sweep_eval_lanes(apply_fn, new_params, Xt_b, yt_b)
         if target_acc is not None:
             done_b = done_b | (acc >= target_acc)
-        if codec_on:
-            return (new_params, done_b, sched_state_b, keys_b,
-                    codec_state_b, r + 1), (acc, T_i, E_i)
-        return (new_params, done_b, sched_state_b, keys_b), (acc, T_i, E_i)
+        return ((new_params, done_b, sched_state_b, keys_b, codec_state_b),
+                (acc, T_i, E_i))
 
-    carry0 = (params_b, done_b, sched_state_b, assign_keys_b)
-    if codec_on:
-        carry0 = carry0 + (codec_state_b, codec_r0)
-    xs = sched_rs if traced_sched is None else None
-    return jax.lax.scan(step, carry0, xs,
-                        length=n_rounds if xs is None else None)
+    carry0 = (params_b, done_b, sched_state_b, assign_keys_b, codec_state_b)
+    return jax.lax.scan(step, carry0, (sched_rs, codec_keys_rs),
+                        length=n_rounds)
 
 
 _SCAN_STATICS = ("apply_fn", "sp", "sp_assign", "M", "L", "Q",
@@ -438,13 +370,14 @@ _SCAN_STATICS = ("apply_fn", "sp", "sp_assign", "M", "L", "Q",
 def sweep_scan(apply_fn, sp: cm.SystemParams, sp_assign, params_b, u_b,
                D_b, p_b, g_b, g_cloud_b, B_m_b, X_b, y_b, mask_b, sizes_b,
                dev_pos_b, edge_pos_b, Xt_b, yt_b, sched_rs, sched_state_b,
-               assign_keys_b, done_b, drl_params, lr, codec_state_b=None,
-               codec_base_b=None, codec_r0=None, *, M: int, L: int,
-               Q: int, alloc_steps: int, train_only: bool = False,
+               assign_keys_b, done_b, drl_params, lr,
+               codec_state_b=(None, None), codec_keys_rs=None, *, M: int,
+               L: int, Q: int, alloc_steps: int, train_only: bool = False,
                agg_kernel: bool = False, lane_chunk: Optional[int] = None,
                assign: str = "geo", hfel_cfg=None,
                target_acc: Optional[float] = None, n_rounds: int = 1,
-               traced_sched=None, codec=None):
+               traced_sched=None,
+               codec: comp.CompressionConfig = comp.IDENTITY):
     """An R-round, S-lane sweep as ONE jitted dispatch.
 
     The whole-sweep analogue of ``sweep_round``: scheduling, assignment
@@ -457,13 +390,13 @@ def sweep_scan(apply_fn, sp: cm.SystemParams, sp_assign, params_b, u_b,
     ``sp_assign`` is the SystemParams the hfel objective scores with
     (the host path's assigner uses the un-patched sweep params, not the
     model-bits-patched round ``sp``). See ``_sweep_scan_lanes`` for the
-    scheduling/assignment operand semantics and the carry layout.
+    scheduling/assignment/codec operand semantics and the carry layout.
     """
     return _sweep_scan_lanes(
         apply_fn, sp, sp_assign, params_b, u_b, D_b, p_b, g_b, g_cloud_b,
         B_m_b, X_b, y_b, mask_b, sizes_b, dev_pos_b, edge_pos_b, Xt_b,
         yt_b, sched_rs, sched_state_b, assign_keys_b, done_b, drl_params,
-        lr, codec_state_b, codec_base_b, codec_r0,
+        lr, codec_state_b, codec_keys_rs,
         M=M, L=L, Q=Q, alloc_steps=alloc_steps, train_only=train_only,
         agg_kernel=agg_kernel, lane_chunk=lane_chunk, assign=assign,
         hfel_cfg=hfel_cfg, target_acc=target_acc, n_rounds=n_rounds,
@@ -475,63 +408,47 @@ def sweep_scan_sharded(apply_fn, sp: cm.SystemParams, sp_assign, params_b,
                        u_b, D_b, p_b, g_b, g_cloud_b, B_m_b, X_b, y_b,
                        mask_b, sizes_b, dev_pos_b, edge_pos_b, Xt_b, yt_b,
                        sched_rs, sched_state_b, assign_keys_b, done_b,
-                       drl_params, lr, codec_state_b=None,
-                       codec_base_b=None, codec_r0=None, *, M: int, L: int,
+                       drl_params, lr, codec_state_b=(None, None),
+                       codec_keys_rs=None, *, M: int, L: int,
                        Q: int, alloc_steps: int, mesh,
                        train_only: bool = False,
                        agg_kernel: bool = False,
                        lane_chunk: Optional[int] = None,
                        assign: str = "geo", hfel_cfg=None,
                        target_acc: Optional[float] = None,
-                       n_rounds: int = 1, traced_sched=None, codec=None):
+                       n_rounds: int = 1, traced_sched=None,
+                       codec: comp.CompressionConfig = comp.IDENTITY):
     """``sweep_scan`` laid out over a 1-D ``Mesh(("lane",))``.
 
     Each device runs the ENTIRE R-round scan — traced scheduling,
     assignment search, round body, eval, done-mask — on its S/d lane
     block as one SPMD program: still exactly one dispatch for the whole
     sweep, now lane-parallel. Lanes are independent, so there are no
-    collectives; the (R, S, H) schedule tensor and the (R, S) outputs
-    shard on their lane axis only (``parallel.sharding.round_lane_spec``).
-    S must be a multiple of the device count (``SweepRunner`` pads with
-    dead done-masked lanes, exactly as in ``sweep_round_sharded``).
+    collectives; the (R, S, H) schedule tensor, the (R, S, 2) codec keys
+    and the (R, S) outputs shard on their lane axis only
+    (``parallel.sharding.round_lane_spec``). S must be a multiple of the
+    device count (``SweepRunner`` pads with dead done-masked lanes,
+    exactly as in ``sweep_round_sharded``).
     """
     from repro.parallel.sharding import round_lane_spec
     lane, rep = PartitionSpec("lane"), PartitionSpec()
     rlane = round_lane_spec()
-    codec_on = codec is not None and codec.active
-
-    def block(params_b, u_b, D_b, p_b, g_b, g_cloud_b, B_m_b, X_b, y_b,
-              mask_b, sizes_b, dev_pos_b, edge_pos_b, Xt_b, yt_b,
-              sched_rs, sched_state_b, assign_keys_b, done_b, drl_params,
-              lr, *cargs):
-        cstate, cbase, cr0 = cargs if codec_on else (None, None, None)
-        return _sweep_scan_lanes(
-            apply_fn, sp, sp_assign, params_b, u_b, D_b, p_b, g_b,
-            g_cloud_b, B_m_b, X_b, y_b, mask_b, sizes_b, dev_pos_b,
-            edge_pos_b, Xt_b, yt_b, sched_rs, sched_state_b,
-            assign_keys_b, done_b, drl_params, lr, cstate, cbase, cr0,
-            M=M, L=L, Q=Q,
-            alloc_steps=alloc_steps, train_only=train_only,
-            agg_kernel=agg_kernel, lane_chunk=lane_chunk, assign=assign,
-            hfel_cfg=hfel_cfg, target_acc=target_acc, n_rounds=n_rounds,
-            traced_sched=traced_sched, codec=codec)
-
-    in_specs = (lane,) * 15 + (rlane, lane, lane, lane, rep, rep)
-    carry_specs = (lane, lane, lane, lane)
-    args = (params_b, u_b, D_b, p_b, g_b, g_cloud_b, B_m_b, X_b,
-            y_b, mask_b, sizes_b, dev_pos_b, edge_pos_b, Xt_b,
-            yt_b, sched_rs, sched_state_b, assign_keys_b, done_b,
-            drl_params, lr)
-    if codec_on:
-        in_specs = in_specs + (lane, lane, rep)
-        carry_specs = carry_specs + (lane, rep)
-        args = args + (codec_state_b, codec_base_b, codec_r0)
+    block = functools.partial(
+        _sweep_scan_lanes, apply_fn, sp, sp_assign, M=M, L=L, Q=Q,
+        alloc_steps=alloc_steps, train_only=train_only,
+        agg_kernel=agg_kernel, lane_chunk=lane_chunk, assign=assign,
+        hfel_cfg=hfel_cfg, target_acc=target_acc, n_rounds=n_rounds,
+        traced_sched=traced_sched, codec=codec)
     sharded = jax.shard_map(
         block, mesh=mesh,
-        in_specs=in_specs,
-        out_specs=(carry_specs, (rlane, rlane, rlane)),
+        in_specs=(lane,) * 15 + (rlane, lane, lane, lane, rep, rep, lane,
+                                 rlane),
+        out_specs=((lane,) * 5, (rlane, rlane, rlane)),
         check_vma=False)
-    return sharded(*args)
+    return sharded(params_b, u_b, D_b, p_b, g_b, g_cloud_b, B_m_b, X_b,
+                   y_b, mask_b, sizes_b, dev_pos_b, edge_pos_b, Xt_b, yt_b,
+                   sched_rs, sched_state_b, assign_keys_b, done_b,
+                   drl_params, lr, codec_state_b, codec_keys_rs)
 
 
 def _mod_assign(pop: cm.Population, sched: np.ndarray, rng) -> np.ndarray:
@@ -688,10 +605,8 @@ class SweepRunner:
     def _codec_state0(self):
         """Fresh lane-stacked error-feedback state: ``(dev_resid
         (S_pad, N, ...), edge_resid (S_pad, M, ...))`` zero trees shaped
-        like one lane's params, lane-sharded when the runner is. None for
-        the identity codec."""
-        if not self.codec.active:
-            return None
+        like one lane's params, lane-sharded when the runner is
+        (``(None, None)`` for the identity codec)."""
         p0 = jax.tree.map(lambda x: x[0], self.params0)
         state = (comp.init_state(self.codec, p0, self.N),
                  comp.init_state(self.codec, p0, self.M))
@@ -703,20 +618,10 @@ class SweepRunner:
             state = jax.tree.map(lambda z: jax.device_put(z, sh), state)
         return state
 
-    def _codec_base_keys(self, seeds):
-        """Per-lane codec key bases ``fold_in(PRNGKey(codec.seed),
-        lane_seed)`` — the host loop folds the round index in per round,
-        the fused scan folds the carried round counter in in-scan, so
-        both engines draw the identical ``compression.round_key``
-        stream."""
-        lane_seeds = jnp.asarray(
-            list(seeds) + [seeds[0]] * self._n_dead, jnp.uint32)
-        base = jax.random.PRNGKey(self.codec.seed)
-        keys = jax.vmap(lambda s: jax.random.fold_in(base, s))(lane_seeds)
-        if self.mesh is not None:
-            from repro.parallel.sharding import lane_sharding
-            keys = jax.device_put(keys, lane_sharding(self.mesh))
-        return keys
+    def _lane_seeds(self, seeds):
+        """(S_pad,) uint32 lane seeds, dead lanes repeating lane 0's."""
+        return jnp.asarray(list(seeds) + [seeds[0]] * self._n_dead,
+                           jnp.uint32)
 
     def _shard_lane_stacks(self):
         """Pad every lane-stacked array up to S_pad with clones of lane 0
@@ -819,9 +724,12 @@ class SweepRunner:
         rngs = [np.random.default_rng(s) for s in seeds]
         sp = dataclasses.replace(self.sp,
                                  model_bits=float(self.uplink_bits))
-        codec_on = self.codec.active
         cstate = self._codec_state0()
-        cbase = self._codec_base_keys(seeds) if codec_on else None
+        lane_seeds = self._lane_seeds(seeds)
+        if self.mesh is not None:
+            round_fn = functools.partial(sweep_round_sharded, mesh=self.mesh)
+        else:
+            round_fn = sweep_round
 
         params_b = self.params0
         accs: List[np.ndarray] = []
@@ -866,34 +774,15 @@ class SweepRunner:
             sched_b = jnp.asarray(np.stack(scheds + pad))
             assign_b = jnp.asarray(np.stack(
                 assigns + [assigns[0]] * self._n_dead))
-            ckw = {}
-            if codec_on:
-                ckw = dict(codec=self.codec, codec_state_b=cstate,
-                           codec_keys_b=jax.vmap(
-                               lambda k: jax.random.fold_in(k, r_i))(cbase))
-            if self.mesh is not None:
-                out = sweep_round_sharded(
-                    self.apply_fn, sp, params_b, self.u_b, self.D_b,
-                    self.p_b, self.g_b, self.g_cloud_b, self.B_m_b,
-                    self.X_b, self.y_b, self.mask_b, sizes_b, sched_b,
-                    assign_b, self.lr, M=self.M, L=sp.L, Q=sp.Q,
-                    alloc_steps=self.alloc_steps, mesh=self.mesh,
-                    train_only=train_only, agg_kernel=self.agg_kernel,
-                    lane_chunk=self.lane_chunk, done_b=jnp.asarray(done),
-                    **ckw)
-            else:
-                out = sweep_round(
-                    self.apply_fn, sp, params_b, self.u_b, self.D_b,
-                    self.p_b, self.g_b, self.g_cloud_b, self.B_m_b,
-                    self.X_b, self.y_b, self.mask_b, sizes_b, sched_b,
-                    assign_b, self.lr, M=self.M, L=sp.L, Q=sp.Q,
-                    alloc_steps=self.alloc_steps, train_only=train_only,
-                    agg_kernel=self.agg_kernel, lane_chunk=self.lane_chunk,
-                    done_b=jnp.asarray(done), **ckw)
-            if codec_on:
-                params_b, (T_i, E_i), cstate = out
-            else:
-                params_b, (T_i, E_i) = out
+            params_b, (T_i, E_i), cstate = round_fn(
+                self.apply_fn, sp, params_b, self.u_b, self.D_b, self.p_b,
+                self.g_b, self.g_cloud_b, self.B_m_b, self.X_b, self.y_b,
+                self.mask_b, sizes_b, sched_b, assign_b, self.lr, M=self.M,
+                L=sp.L, Q=sp.Q, alloc_steps=self.alloc_steps,
+                train_only=train_only, agg_kernel=self.agg_kernel,
+                lane_chunk=self.lane_chunk, done_b=jnp.asarray(done),
+                codec=self.codec, codec_state_b=cstate,
+                codec_keys_b=comp.round_key(self.codec, lane_seeds, r_i))
             acc_full = self._eval(params_b)              # (S_pad,)
             acc = acc_full[:self.S]
             accs.append(acc)
@@ -960,10 +849,11 @@ class SweepRunner:
             seeds = list(range(self.S))
         sp = dataclasses.replace(self.sp,
                                  model_bits=float(self.uplink_bits))
-        codec_on = self.codec.active
         cstate = self._codec_state0()
-        cbase = self._codec_base_keys(seeds) if codec_on else None
-        cr = jnp.int32(0) if codec_on else None
+        lane_seeds = self._lane_seeds(seeds)
+        # every round's per-lane codec key, (R, S_pad, 2): scan inputs
+        ckeys = comp.round_key(self.codec, lane_seeds,
+                               jnp.arange(n_rounds)[:, None])
 
         # -- scheduling: in-scan TracedFedAvg state, or an exact host
         #    precompute (scheduling never reads training state, so the
@@ -1014,8 +904,6 @@ class SweepRunner:
             sched_rs = jnp.asarray(np.stack(rounds))     # (R, S_pad, H)
 
         base = jax.random.PRNGKey(assign_seed)
-        lane_seeds = jnp.asarray(
-            list(seeds) + [seeds[0]] * self._n_dead, jnp.uint32)
         assign_keys_b = jax.vmap(
             lambda s: jax.random.fold_in(base, s))(lane_seeds)
         done0 = np.zeros(self.S_pad, bool)
@@ -1026,23 +914,22 @@ class SweepRunner:
                        train_only=train_only, agg_kernel=self.agg_kernel,
                        lane_chunk=self.lane_chunk, assign=assign,
                        hfel_cfg=hfel_cfg, target_acc=target_acc,
-                       traced_sched=traced_sched,
-                       codec=self.codec if codec_on else None)
+                       traced_sched=traced_sched, codec=self.codec)
         if self.mesh is not None:
             fn = functools.partial(sweep_scan_sharded, mesh=self.mesh)
         else:
             fn = sweep_scan
 
         def dispatch(params_b, done_b, sched_state_b, assign_keys_b,
-                     sched_rs, n_r, codec_state_b=None, codec_r0=None):
+                     codec_state_b, sched_rs, codec_keys_rs, n_r):
             return fn(self.apply_fn, sp, self.sp, params_b, self.u_b,
                       self.D_b, self.p_b, self.g_b, self.g_cloud_b,
                       self.B_m_b, self.X_b, self.y_b, self.mask_b, sizes_b,
                       self.dev_pos_b, self.edge_pos_b, self.Xt_b, self.yt_b,
                       sched_rs, sched_state_b, assign_keys_b, done_b,
                       drl_params if assign == "drl" else None, self.lr,
-                      codec_state_b, cbase, codec_r0,
-                      n_rounds=n_r, **statics)
+                      codec_state_b, codec_keys_rs, n_rounds=n_r,
+                      **statics)
 
         if oracle:
             # per-round host loop over the SAME traced step: the fused
@@ -1050,15 +937,12 @@ class SweepRunner:
             accs, Ts, Es = [], [], []
             n_dispatches = 0
             for r in range(n_rounds):
-                xs_r = None if sched_rs is None else sched_rs[r:r + 1]
+                rows = slice(r, r + 1)
                 carry, (acc_r, T_r, E_r) = dispatch(
-                    params_b, done_b, sched_state_b, assign_keys_b, xs_r, 1,
-                    cstate, cr)
-                if codec_on:
-                    (params_b, done_b, sched_state_b, assign_keys_b,
-                     cstate, cr) = carry
-                else:
-                    params_b, done_b, sched_state_b, assign_keys_b = carry
+                    params_b, done_b, sched_state_b, assign_keys_b, cstate,
+                    None if sched_rs is None else sched_rs[rows],
+                    comp.key_at(ckeys, rows), 1)
+                params_b, done_b, sched_state_b, assign_keys_b, cstate = carry
                 n_dispatches += 1
                 accs.append(np.asarray(acc_r)[0, :self.S])
                 Ts.append(np.asarray(T_r)[0, :self.S])
@@ -1070,8 +954,8 @@ class SweepRunner:
             E_a = np.stack(Es, axis=1)
         else:
             _, (acc_rs, T_rs, E_rs) = dispatch(
-                params_b, done_b, sched_state_b, assign_keys_b, sched_rs,
-                n_rounds, cstate, cr)
+                params_b, done_b, sched_state_b, assign_keys_b, cstate,
+                sched_rs, ckeys, n_rounds)
             n_dispatches = 1
             acc_a = np.asarray(acc_rs)[:, :self.S].T     # (S, R)
             T_a = np.asarray(T_rs)[:, :self.S].T

@@ -120,7 +120,8 @@ def _one_ulp_response(round_fn, params):
     return resp
 
 
-def test_degenerate_trace_matches_round_step_oracle():
+@pytest.mark.parametrize("codec", ["none", "bf16_delta", "topk"])
+def test_degenerate_trace_matches_round_step_oracle(codec):
     """Zero-latency-skew/zero-dropout async == synchronous round_step:
     allocations bitwise, costs to accumulation-order tolerance, params
     and accuracy within the summation-order bound below — every round,
@@ -131,29 +132,42 @@ def test_degenerate_trace_matches_round_step_oracle():
     gemm. With at most 3 members per edge (H=6, M=3) the two sums differ
     by at most 2 ulp per aggregation, Q aggregations per round, so the
     params may differ by up to 2·Q times the oracle's own response to a
-    one-ulp change of its input (``_one_ulp_response``)."""
-    sp, pop, fed = _world(seed=0)
-    cfg = AsyncConfig(H=H, scheduler="fedavg", alloc_steps=ALLOC_STEPS,
-                      seed=3)
-    eng = AsyncHFLEngine(sp, pop, fed, cfg)
-    spp = eng.sp                       # model_bits-patched params
+    one-ulp change of its input (``_one_ulp_response``).
 
-    for _ in range(2):
+    The lossy cases (the deterministic codecs) pin the one definition of
+    a lossy message both engines share: the async buffers hold what the
+    edge receives, ``pulled + decode(msg)``, and flush it in model space;
+    the sync round adds the decoded messages to the edge model in delta
+    space. Params agree to the same bound.
+    """
+    sp, pop, fed = _world(seed=0)
+    cc = comp.CompressionConfig(codec=codec)
+    cfg = AsyncConfig(H=H, scheduler="fedavg", alloc_steps=ALLOC_STEPS,
+                      seed=3, compression=cc)
+    eng = AsyncHFLEngine(sp, pop, fed, cfg)
+    spp = eng.sp_round                 # model/uplink-bits-patched params
+
+    for r in range(2):
         params_start = eng.model_params
+        dev_resid, edge_resid = eng.dev_resid, eng.edge_resid
         rec = eng.step_round()
         sched, assign = eng.last_sched, eng.last_assign
 
         def sync_round(params):
             return round_step(
-                eng.apply_fn, spp, params,
+                eng.apply_fn, spp,
+                (params, (jax.tree.map(lambda x: x[sched], dev_resid),
+                          edge_resid)),
                 pop.u[sched], pop.D[sched], pop.p[sched], pop.g[sched],
                 pop.g_cloud, pop.B_m,
                 eng.X[sched], eng.y[sched], eng.mask[sched],
                 pop.D[sched], jnp.asarray(assign, jnp.int32), cfg.lr,
                 M=pop.n_edges, L=spp.L, Q=spp.Q,
-                alloc_steps=cfg.alloc_steps)
+                alloc_steps=cfg.alloc_steps, codec=cc,
+                codec_key=comp.round_key(cc, cfg.seed, r))
 
-        params_sync, (T, E, _, _, b, f) = sync_round(params_start)
+        (params_sync, resid_sync), (T, E, _, _, b, f) = sync_round(
+            params_start)
 
         b_a, f_a = eng.last_alloc[:2]
         np.testing.assert_array_equal(np.asarray(b_a), np.asarray(b))
@@ -164,13 +178,18 @@ def test_degenerate_trace_matches_round_step_oracle():
         assert rec["n_stale"] == 0 and rec["n_aborted"] == 0
         assert rec["forced_flushes"] == 0
         assert rec["msg_bits"] == pytest.approx(
-            (spp.Q * H + pop.n_edges) * spp.model_bits)
-        resp = _one_ulp_response(lambda p: sync_round(p)[0], params_start)
-        for pa, pb, r in zip(jax.tree.leaves(eng.model_params),
-                             jax.tree.leaves(params_sync), resp):
+            (spp.Q * H + pop.n_edges) * eng.uplink_bits)
+        resp = _one_ulp_response(lambda p: sync_round(p)[0][0],
+                                 params_start)
+        for pa, pb, rp in zip(jax.tree.leaves(eng.model_params),
+                              jax.tree.leaves(params_sync), resp):
             np.testing.assert_allclose(np.asarray(pa), np.asarray(pb),
                                        rtol=2e-6,
-                                       atol=max(2e-7, 2 * spp.Q * r))
+                                       atol=max(2e-7, 2 * spp.Q * rp))
+        # the residuals' structure (a codec step may round one ulp of
+        # difference either way, so their values are not compared)
+        assert (jax.tree.structure((eng.dev_resid, eng.edge_resid))
+                == jax.tree.structure(resid_sync))
         acc_sync = evaluate_in_batches(eng.apply_fn, params_sync,
                                        fed.X_test, fed.y_test)
         assert rec["acc"] == pytest.approx(acc_sync, abs=1e-6)
@@ -252,7 +271,10 @@ def test_chunked_dispatch_matches_the_masked_oracle(chunk_world, name):
     w, dmask = chunk_world, _dispatch_mask(name)
     args = (w["apply_fn"], w["cohort"], w["edge"], w["assign"], dmask,
             w["X"], w["y"], w["mask"], w["lr"])
-    got = ae._train_dispatched(*args, L=w["L"])
+    got, resid = ae._train_dispatched(
+        args[0], (w["cohort"], None), *args[2:], None, L=w["L"],
+        codec=comp.CompressionConfig())
+    assert resid is None
     _, trained = _masked_oracle(*args, L=w["L"])
     _assert_rows(got, trained, w["cohort"], dmask)
 
@@ -270,12 +292,12 @@ def test_chunked_compressed_dispatch_matches_the_masked_oracle(
         dmask = _dispatch_mask(name)
         args = (w["apply_fn"], w["cohort"], w["edge"], w["assign"], dmask,
                 w["X"], w["y"], w["mask"], w["lr"])
-        got, got_resid = ae._train_dispatched_compressed(
-            *args, resid, key, L=w["L"], codec=cc)
+        got, got_resid = ae._train_dispatched(
+            args[0], (w["cohort"], resid), *args[2:], key, L=w["L"],
+            codec=cc)
         pulled, trained = _masked_oracle(*args, L=w["L"])
-        delta = jax.tree.map(lambda t, q: t - q, trained, pulled)
-        dec, want_resid = comp.encode_decode(cc, key, delta, resid)
-        recon = jax.tree.map(jnp.add, pulled, dec)
+        msg, want_resid = comp.uplink(cc, key, pulled, trained, resid)
+        recon = comp.receive(cc, msg, pulled)
         _assert_rows(got, recon, w["cohort"], dmask)
         _assert_rows(got_resid, want_resid, resid, dmask)
 
@@ -293,8 +315,9 @@ def test_chunked_int8_dispatch_keeps_error_feedback_exact(chunk_world):
         on = np.asarray(dmask)
         args = (w["apply_fn"], w["cohort"], w["edge"], w["assign"], dmask,
                 w["X"], w["y"], w["mask"], w["lr"])
-        got, got_resid = ae._train_dispatched_compressed(
-            *args, resid, jax.random.PRNGKey(4), L=w["L"], codec=cc)
+        got, got_resid = ae._train_dispatched(
+            args[0], (w["cohort"], resid), *args[2:],
+            jax.random.PRNGKey(4), L=w["L"], codec=cc)
         pulled, trained = _masked_oracle(*args, L=w["L"])
         for g, r, t, q, o in zip(*(jax.tree.leaves(a) for a in (
                 got, got_resid, trained, pulled, resid))):

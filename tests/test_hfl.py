@@ -26,8 +26,8 @@ def test_edge_and_cloud_aggregation_weights():
     mask = jnp.ones((H, Dmax))
     sizes = jnp.asarray([1., 2., 3., 4., 5., 6.])
     assign = jnp.asarray([0, 0, 1, 1, 2, 2])
-    out = hfl_global_iteration(_linear_apply, w0, X, y, mask, sizes, assign,
-                               M=3, L=2, Q=2, lr=0.0)
+    out, _ = hfl_global_iteration(_linear_apply, w0, X, y, mask, sizes,
+                                  assign, M=3, L=2, Q=2, lr=0.0)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(w0["w"]),
                                atol=1e-6)
 
@@ -40,9 +40,9 @@ def test_single_device_single_edge_equals_local_sgd():
     y1 = jnp.asarray(rng.integers(0, 3, (1, 8)).astype(np.int32))
     m1 = jnp.ones((1, 8))
     w0 = {"w": jnp.asarray(rng.normal(0, 0.1, (4, 3)).astype(np.float32))}
-    out = hfl_global_iteration(_linear_apply, w0, X1, y1, m1,
-                               jnp.ones(1), jnp.zeros(1, jnp.int32),
-                               M=1, L=3, Q=2, lr=0.05)
+    out, _ = hfl_global_iteration(_linear_apply, w0, X1, y1, m1,
+                                  jnp.ones(1), jnp.zeros(1, jnp.int32),
+                                  M=1, L=3, Q=2, lr=0.05)
     # manual: Q rounds of (L local steps from the aggregated model)
     w = w0
     for _ in range(2):
@@ -63,7 +63,7 @@ def test_hfl_cnn_learns_synthetic():
     assign = np.asarray(sched % 3)
     acc0 = evaluate_in_batches(cnn.cnn_apply, params, fed.X_test, fed.y_test)
     for _ in range(3):
-        params = hfl_global_iteration(
+        params, _ = hfl_global_iteration(
             cnn.cnn_apply, params, Xp[sched], yp[sched], mask[sched],
             jnp.asarray(fed.sizes[sched], jnp.float32), jnp.asarray(assign),
             M=3, L=3, Q=2, lr=0.02)   # lr=0.05 diverges on this tiny split
@@ -77,7 +77,7 @@ def test_empty_edge_keeps_model_valid():
     X = jnp.zeros((H, Dmax, 2, 2, 1))
     y = jnp.zeros((H, Dmax), jnp.int32)
     mask = jnp.ones((H, Dmax))
-    out = hfl_global_iteration(_linear_apply, w0, X, y, mask,
-                               jnp.ones(H), jnp.zeros(H, jnp.int32),
-                               M=3, L=1, Q=1, lr=0.0)   # edges 1,2 empty
+    out, _ = hfl_global_iteration(_linear_apply, w0, X, y, mask,
+                                  jnp.ones(H), jnp.zeros(H, jnp.int32),
+                                  M=3, L=1, Q=1, lr=0.0)   # edges 1,2 empty
     assert bool(jnp.all(jnp.isfinite(out["w"])))

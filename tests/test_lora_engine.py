@@ -27,7 +27,7 @@ def test_round_holds_no_copy_of_the_base_per_lane_or_edge(setup):
     sp = cm.SystemParams(n_devices=8, n_edges=M, L=2, Q=2)
     g = jnp.ones((H, M), f32)
     text = round_step.lower(
-        s["spec"].apply_fn, sp, s["params"], jnp.ones(H, f32),
+        s["spec"].apply_fn, sp, (s["params"], (None, None)), jnp.ones(H, f32),
         jnp.ones(H, f32), jnp.ones(H, f32), g, jnp.ones(M, f32),
         jnp.ones(M, f32), jnp.asarray(s["X"]), jnp.asarray(s["y"]),
         jnp.asarray(s["mask"]), jnp.asarray(s["sizes"]),

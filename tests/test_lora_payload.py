@@ -173,8 +173,9 @@ def test_one_round_matches_the_reference(setup):
     s, m = setup, setup["m"]
     args = [jnp.asarray(s[k]) for k in ("X", "y", "mask", "sizes",
                                         "assign")]
-    got = hfl_global_iteration(s["spec"].apply_fn, s["params"], *args,
-                               M=M, L=1, Q=2, lr=0.5, frozen=s["frozen"])
+    got, _ = hfl_global_iteration(s["spec"].apply_fn, s["params"], *args,
+                                  M=M, L=1, Q=2, lr=0.5,
+                                  frozen=s["frozen"])
     with jax.default_matmul_precision("highest"):
         want = reference_lm.hfl_round(s["weights"], s["ad"], *args, M=M,
                                       L=1, Q=2, lr=0.5, block=2, m=m)

@@ -138,9 +138,10 @@ def test_hfl_cnn_bitwise_parity_with_pre_spec_engines():
     sched = np.asarray(FedAvgScheduler(fed.n_devices, _H).schedule(rng))
     assign, _ = GeoAssigner(sp_r).assign(pop, sched, rng)
     X, y, mask = pad_device_data(fed)
-    new_params, (T_i, E_i, _, _, _, _) = round_step(
-        cnn.cnn_apply, sp_r, ref, pop.u[sched], pop.D[sched], pop.p[sched],
-        pop.g[sched], pop.g_cloud, pop.B_m, X[sched], y[sched], mask[sched],
+    (new_params, _), (T_i, E_i, _, _, _, _) = round_step(
+        cnn.cnn_apply, sp_r, (ref, (None, None)), pop.u[sched],
+        pop.D[sched], pop.p[sched], pop.g[sched], pop.g_cloud, pop.B_m,
+        X[sched], y[sched], mask[sched],
         pop.D[sched], jnp.asarray(np.asarray(assign)), cfg.lr,
         M=pop.n_edges, L=sp.L, Q=sp.Q, alloc_steps=cfg.alloc_steps)
     for a, b in zip(jax.tree.leaves(new_params),

@@ -97,15 +97,16 @@ def test_fused_round_step_matches_sequential_components():
     T_i, E_i, _, _ = cm.round_cost(sp, pop, jnp.asarray(sched),
                                    jnp.asarray(assign), jnp.asarray(b),
                                    jnp.asarray(f))
-    w_seq = hfl_global_iteration(_linear_apply, w0, X, y, mask,
-                                 pop.D[sched], jnp.asarray(assign),
-                                 M=3, L=2, Q=2, lr=0.05)
+    w_seq, _ = hfl_global_iteration(_linear_apply, w0, X, y, mask,
+                                    pop.D[sched], jnp.asarray(assign),
+                                    M=3, L=2, Q=2, lr=0.05)
 
     # fused engine
-    w_fused, (T_f, E_f, _, _, b_f, f_f) = round_step(
-        _linear_apply, sp, w0, pop.u[sched], pop.D[sched], pop.p[sched],
-        pop.g[sched], pop.g_cloud, pop.B_m, X, y, mask, pop.D[sched],
-        jnp.asarray(assign), 0.05, M=3, L=2, Q=2, alloc_steps=ALLOC_STEPS)
+    (w_fused, _), (T_f, E_f, _, _, b_f, f_f) = round_step(
+        _linear_apply, sp, (w0, (None, None)), pop.u[sched], pop.D[sched],
+        pop.p[sched], pop.g[sched], pop.g_cloud, pop.B_m, X, y, mask,
+        pop.D[sched], jnp.asarray(assign), 0.05, M=3, L=2, Q=2,
+        alloc_steps=ALLOC_STEPS)
 
     np.testing.assert_allclose(float(T_i), float(T_f), rtol=1e-5)
     np.testing.assert_allclose(float(E_i), float(E_f), rtol=1e-5)
@@ -139,9 +140,9 @@ def test_hfl_iteration_agg_kernel_matches_einsum(dtype):
     w0 = {"w": w0["w"].astype(dtype)}
     outs = {}
     for ak in (False, True):
-        w = hfl_global_iteration(_linear_apply, w0, X.astype(dtype), y,
-                                 mask, pop.D[sched], jnp.asarray(assign),
-                                 M=3, L=2, Q=2, lr=0.05, agg_kernel=ak)
+        w, _ = hfl_global_iteration(_linear_apply, w0, X.astype(dtype), y,
+                                    mask, pop.D[sched], jnp.asarray(assign),
+                                    M=3, L=2, Q=2, lr=0.05, agg_kernel=ak)
         outs[ak] = np.asarray(w["w"], np.float32)
     tol = 1e-5 if dtype == jnp.float32 else 0.03
     np.testing.assert_allclose(outs[True], outs[False], rtol=tol, atol=tol)
@@ -154,8 +155,8 @@ def test_round_step_agg_kernel_matches_einsum_oracle():
     sp, pop, sched, assign, X, y, mask, w0 = _toy_round_inputs()
     outs = {}
     for ak in (False, True):
-        w, (T_i, E_i, _, _, b, f) = round_step(
-            _linear_apply, sp, w0, pop.u[sched], pop.D[sched],
+        (w, _), (T_i, E_i, _, _, b, f) = round_step(
+            _linear_apply, sp, (w0, (None, None)), pop.u[sched], pop.D[sched],
             pop.p[sched], pop.g[sched], pop.g_cloud, pop.B_m, X, y, mask,
             pop.D[sched], jnp.asarray(assign), 0.05, M=3, L=2, Q=2,
             alloc_steps=ALLOC_STEPS, agg_kernel=ak)
@@ -189,8 +190,8 @@ def test_sweep_round_agg_kernel_vmapped_lanes():
             stack(X), stack(y), stack(mask), stack(pop.D), stack(sched),
             assign_b, 0.05)
     kw = dict(M=3, L=2, Q=2, alloc_steps=60)
-    p_k, (T_k, E_k) = sweep_round(*args, **kw, agg_kernel=True)
-    p_e, (T_e, E_e) = sweep_round(*args, **kw, agg_kernel=False)
+    p_k, (T_k, E_k), _ = sweep_round(*args, **kw, agg_kernel=True)
+    p_e, (T_e, E_e), _ = sweep_round(*args, **kw, agg_kernel=False)
     np.testing.assert_allclose(np.asarray(p_k["w"]), np.asarray(p_e["w"]),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(T_k), np.asarray(T_e), rtol=1e-6)
@@ -214,8 +215,8 @@ def test_sweep_round_done_mask_freezes_lane():
             stack(X), stack(y), stack(mask), stack(pop.D), stack(sched),
             assign_b, 0.05)
     kw = dict(M=3, L=2, Q=2, alloc_steps=60)
-    p_all, (T_all, E_all) = sweep_round(*args, **kw)
-    p_msk, (T_msk, E_msk) = sweep_round(
+    p_all, (T_all, E_all), _ = sweep_round(*args, **kw)
+    p_msk, (T_msk, E_msk), _ = sweep_round(
         *args, **kw, done_b=jnp.asarray([True, False]))
     # lane 0 frozen: params unchanged, zero costs
     np.testing.assert_array_equal(np.asarray(p_msk["w"][0]),

@@ -315,7 +315,7 @@ def test_done_mask_freeze_invariant(mask_bits, n_rounds):
     done = np.array([(mask_bits >> i) & 1 == 1 for i in range(3)])
     params = runner.params0
     for _ in range(n_rounds):
-        new_params, (T_i, E_i) = sweep_round(
+        new_params, (T_i, E_i), _ = sweep_round(
             runner.apply_fn, spp, params, runner.u_b, runner.D_b,
             runner.p_b, runner.g_b, runner.g_cloud_b, runner.B_m_b,
             runner.X_b, runner.y_b, runner.mask_b, runner.D_b, sched,

@@ -124,7 +124,7 @@ def test_kernel_round_step_compiles_for_v5e(one_chip, no_compile_cache,
                          model_bits=float(P_CNN * 32))
     vec = _spec((H,), f32, one_chip)
     compiled = round_step.lower(
-        cnn_spec().apply_fn, sp, params, vec, vec, vec,
+        cnn_spec().apply_fn, sp, (params, (None, None)), vec, vec, vec,
         _spec((H, M), f32, one_chip), _spec((M,), f32, one_chip),
         _spec((M,), f32, one_chip),
         _spec((H, D_MAX, 28, 28, 1), f32, one_chip),

@@ -52,13 +52,12 @@ def test_round_step_program_carries_the_layer_scopes(fw, agg_kernel, codec):
     kw = dict(M=pop.n_edges, L=sp.L, Q=sp.Q, alloc_steps=20,
               agg_kernel=agg_kernel)
     cc = comp.CompressionConfig(codec=codec)
-    if cc.active:
-        kw.update(codec=cc, codec_key=comp.round_key(cc, 0, 1),
-                  codec_state=(comp.init_state(cc, fw.model_params, H),
-                               comp.init_state(cc, fw.model_params,
-                                               pop.n_edges)))
+    kw.update(codec=cc, codec_key=comp.round_key(cc, 0, 1))
+    state = (fw.model_params,
+             (comp.init_state(cc, fw.model_params, H),
+              comp.init_state(cc, fw.model_params, pop.n_edges)))
     hlo = round_step.lower(
-        fw.apply_fn, sp, fw.model_params, pop.u[sched], pop.D[sched],
+        fw.apply_fn, sp, state, pop.u[sched], pop.D[sched],
         pop.p[sched], pop.g[sched], pop.g_cloud, pop.B_m, fw.X[sched],
         fw.y[sched], fw.mask[sched], pop.D[sched],
         jnp.asarray(sched % pop.n_edges), 0.01, **kw).compile().as_text()
@@ -112,15 +111,13 @@ def test_run_round_leaves_the_round_program_running(fw, monkeypatch):
                                                (1, "int8")])
 def test_dispatch_counters_match_the_dispatch_masks(world, monkeypatch,
                                                     buffer_size, codec):
-    attr = ("_train_dispatched_compressed" if codec != "none"
-            else "_train_dispatched")
-    real, lanes = getattr(ae, attr), []
+    real, lanes = ae._train_dispatched, []
 
     def counting(*args, **kwargs):
         lanes.append(int(np.asarray(args[4]).sum()))    # dispatch_mask
         return real(*args, **kwargs)
 
-    monkeypatch.setattr(ae, attr, counting)
+    monkeypatch.setattr(ae, "_train_dispatched", counting)
     eng = ae.AsyncHFLEngine(*world, ae.AsyncConfig(
         H=H, alloc_steps=20, seed=0, buffer_size=buffer_size,
         compression=comp.CompressionConfig(codec=codec)))
